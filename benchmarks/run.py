@@ -1,13 +1,10 @@
 """Benchmark harness — one module per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--only fig8,...]
-                                          [--hardware]
 
 Prints ``name,us_per_call,derived`` CSV rows (see benchmarks/common.py) and
-writes reports/benchmarks.json.  ``--hardware`` appends the opt-in
-real-accelerator lane (``benchmarks.bench_hardware``: compiled Pallas,
-``interpret=False``) — wall-clock only, never count-gated, and it skips
-itself cleanly when no accelerator backend is attached.
+writes reports/benchmarks.json.  Compiles land in the persistent cache of
+``repro.launch.compile_cache``.
 """
 
 from __future__ import annotations
@@ -40,22 +37,17 @@ def main() -> None:
                     help="paper-scale sizes (slow)")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: "
-                         + ",".join(k for k, _ in MODULES) + ",hardware")
-    ap.add_argument("--hardware", action="store_true",
-                    help="append the real-accelerator lane "
-                         "(compiled Pallas, interpret=False; "
-                         "skips cleanly on CPU-only hosts)")
+                         + ",".join(k for k, _ in MODULES))
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
-    modules = list(MODULES)
-    if args.hardware:
-        modules.append(("hardware", "benchmarks.bench_hardware"))
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     import importlib
     all_rows = []
     print("name,us_per_call,derived")
-    for key, modname in modules:
+    for key, modname in MODULES:
         if only and key not in only:
             continue
         t0 = time.time()

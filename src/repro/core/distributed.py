@@ -219,7 +219,7 @@ def flatten_net(net: ReferenceNet, pivot_level: Optional[int] = None
         envelopes=envs)
 
 
-def _batch_dist(dist_name: str, qs, xs, interpret=True):
+def _batch_dist(dist_name: str, qs, xs, interpret=None):
     """Deprecated since v0.1, removed in v0.2: batched distance lives in
     the kernel registry — call
     ``repro.kernels.registry.get(name).device_call(qs, xs)`` (or, from the
@@ -236,7 +236,8 @@ def _batch_dist(dist_name: str, qs, xs, interpret=True):
 
 
 def device_range_query(flat: FlatNet, qs: np.ndarray, eps: float, *,
-                       capacity: Optional[int] = None, interpret: bool = True,
+                       capacity: Optional[int] = None,
+                       interpret: Optional[bool] = None,
                        q_lens: Optional[np.ndarray] = None,
                        lb_cascade="off") -> Tuple[np.ndarray, dict]:
     """Batched exact range query on one shard.
@@ -257,6 +258,8 @@ def device_range_query(flat: FlatNet, qs: np.ndarray, eps: float, *,
     """
     Q = qs.shape[0]
     N = len(flat.data)
+    # resolved outside the jit: a static None would pin a stale policy
+    interpret = kernel_registry.resolve_interpret(interpret)
     if capacity is None:
         capacity = max(64, N // 4) * Q
     if q_lens is None:

@@ -36,7 +36,7 @@ def pairwise_l2_pallas(x, y, *, bm: int = 128, bn: int = 128,
     ``interpret=None`` resolves through the registry's single process-wide
     interpret policy (``registry.default_interpret()``) — resolution
     happens *outside* the jitted inner so a later policy change (the
-    ``set_default_interpret`` hook, the hardware lane) is never shadowed
+    ``set_default_interpret`` hook) is never shadowed
     by a stale jit cache entry keyed on None.
     """
     return _pairwise_l2_jit(x, y, bm=bm, bn=bn,

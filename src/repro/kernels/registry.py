@@ -17,7 +17,8 @@ registry is that substrate's single entry point:
 * one ``interpret`` policy: resolved against the default JAX backend once
   per process (:func:`default_interpret`), not per call — overridable via
   the ``REPRO_INTERPRET`` env var or the :func:`set_default_interpret`
-  test/bench hook (the real-hardware benchmark lane pins ``False``);
+  test hook.  Callers pass ``interpret=None`` to follow it, so a TPU
+  always runs the compiled kernels;
 * one execution-mode policy for the wavefront specs
   (:func:`default_exec`): ``"pallas"`` (the banded VMEM-blocked kernel —
   interpret-mode off-TPU, real hardware on TPU) or ``"scan"`` (the
@@ -54,7 +55,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.wavefront import (BIG, wavefront_pallas, wavefront_scan)
+from repro.kernels.wavefront import (BIG, vmem_bytes, wavefront_pallas,
+                                     wavefront_scan)
 
 #: wavefront mode <-> distance-registry name
 MODE_OF_NAME = {"dtw": "dtw", "erp": "erp", "frechet": "dfd",
@@ -65,7 +67,22 @@ NAME_OF_MODE = {v: k for k, v in MODE_OF_NAME.items()}
 #: (the retrace regression tests pin it), ``calls`` once per host dispatch.
 STATS = {"traces": 0, "calls": 0}
 
-_JIT_CACHE: Dict[tuple, object] = {}
+
+class CacheKey(NamedTuple):
+    """One compiled shape class of the jit cache."""
+    name: str
+    x_shape: tuple
+    x_dtype: str
+    y_shape: tuple
+    y_dtype: str
+    batch: int
+    block_b: int
+    interpret: bool
+    exec: Optional[str]
+    tile: Optional[int]
+
+
+_JIT_CACHE: Dict[CacheKey, object] = {}
 _DEFAULT_INTERPRET: Optional[bool] = None
 
 #: wavefront execution modes: the banded Pallas kernel vs the compiled
@@ -73,9 +90,10 @@ _DEFAULT_INTERPRET: Optional[bool] = None
 EXEC_MODES = ("pallas", "scan")
 _DEFAULT_EXEC: Optional[str] = None
 
-#: per-band VMEM budget (bytes) for the tiled wavefront — a conservative
-#: slice of the ~16 MiB/core TPU VMEM, leaving room for double buffering
-VMEM_TILE_BUDGET = 1 << 22
+#: VMEM budget (bytes) for one grid cell of the tiled wavefront, as
+#: :func:`repro.kernels.wavefront.vmem_bytes` counts it: under the 16 MiB
+#: default scoped limit of a TPU v5e core
+VMEM_TILE_BUDGET = 12 << 20
 
 
 class KernelOut(NamedTuple):
@@ -96,8 +114,7 @@ def default_interpret() -> bool:
     Resolution order: a value pinned by :func:`set_default_interpret`, the
     ``REPRO_INTERPRET`` env var (``1/true/yes/on`` vs anything else), then
     the JAX backend (interpret everywhere except TPU).  The env override
-    lets tests and the ``--hardware`` benchmark lane pin the policy
-    without import-order games."""
+    lets tests pin the policy without import-order games."""
     global _DEFAULT_INTERPRET
     if _DEFAULT_INTERPRET is None:
         env = os.environ.get("REPRO_INTERPRET")
@@ -169,19 +186,18 @@ def default_tile(Lx: int, Ly: int, d: int, block_b: int = 8,
                  budget: int = VMEM_TILE_BUDGET) -> int:
     """Deepest anti-diagonal band whose working set fits the VMEM budget.
 
-    The banded kernel's per-band, per-batch-block f32 residency is the x
-    tile (``(Lx+1)*d``), the band's reversed-y tile (``(Lx+tile)*(d+1)``
-    including the ERP gap row), the borders, and the carry scratch (two
-    diagonals + answer/liveness columns); only the y tile scales with the
-    band depth, so the deepest admissible tile is linear in the budget.
-    Clamped to ``[8, Lx+Ly]`` — on short segments (every CI bench shape)
-    the whole DP fits one band, which is exactly the untiled schedule.
+    The working set is :func:`~repro.kernels.wavefront.vmem_bytes` (lane-
+    and sublane-padded, double-buffered); only the band's reversed-y tile
+    scales with the depth, so the deepest admissible tile is linear in the
+    budget.  Clamped to ``[8, Lx+Ly]`` — on short segments (every CI bench
+    shape) the whole DP fits one band, which is exactly the untiled
+    schedule; where even 8 diagonals overrun the budget the kernel raises
+    its own VMEM limit to match.
     """
-    W = Lx + 1
     K = Lx + Ly
-    fixed = W * d + Lx * (d + 1) + W + (Ly + 1) + 2 * W + 8
-    per_t = d + 1
-    T = (budget // (4 * block_b) - fixed) // per_t
+    base = vmem_bytes(Lx, Ly, d, 0, block_b)
+    per_t = (vmem_bytes(Lx, Ly, d, 8 * K, block_b) - base) // (8 * K)
+    T = (budget - base) // per_t
     return max(8, min(int(T), K))
 
 
@@ -190,6 +206,11 @@ def clear_cache() -> None:
     _JIT_CACHE.clear()
     STATS["traces"] = 0
     STATS["calls"] = 0
+
+
+def cache_keys() -> list:
+    """The shape classes compiled so far (what ran, and how)."""
+    return list(_JIT_CACHE)
 
 
 def _pad_pow2(n: int) -> int:
@@ -356,9 +377,12 @@ class KernelSpec:
             gx = jnp.where(jnp.arange(Lx)[None, :] < lx[:, None], gx, 0.0)
             gy = jnp.where(jnp.arange(Ly)[None, :] < ly[:, None], gy, 0.0)
             gap_x = jnp.pad(gx, ((0, 0), (1, 0)))
-            gy_rev = jnp.flip(gy, axis=1)
-            gap_y_rev = jnp.pad(gy_rev,
+            # y's gap cost rides reversed y as one extra channel, so the
+            # kernel slices both with one sublane-offset window load
+            gap_y_rev = jnp.pad(jnp.flip(gy, axis=1),
                                 ((0, 0), (Lx + 1, Ypad - (Lx + 1) - Ly)))
+            y_rev_pad = jnp.concatenate([y_rev_pad, gap_y_rev[..., None]],
+                                        axis=2)
             zero = jnp.zeros((B, 1), jnp.float32)
             # clamp: a cumsum above the BIG sentinel would corrupt the DP's
             # quasi-infinity ordering (and overflow to inf three adds later)
@@ -368,21 +392,21 @@ class KernelSpec:
                 jnp.concatenate([zero, jnp.cumsum(gy, 1)], axis=1), BIG)
         else:
             gap_x = jnp.zeros((B, Lx + 1), jnp.float32)
-            gap_y_rev = jnp.zeros((B, Ypad), jnp.float32)
             if mode == "lev":
                 border_col = jnp.broadcast_to(
                     jnp.arange(Lx + 1, dtype=jnp.float32)[None], (B, Lx + 1))
                 border_row = jnp.broadcast_to(
                     jnp.arange(Ly + 1, dtype=jnp.float32)[None], (B, Ly + 1))
             else:
-                big = jnp.float32(BIG)
-                border_col = jnp.full((B, Lx + 1), big).at[:, 0].set(0.0)
-                border_row = jnp.full((B, Ly + 1), big).at[:, 0].set(0.0)
+                border_col = jnp.where(jnp.arange(Lx + 1)[None] == 0, 0.0,
+                                       jnp.full((B, Lx + 1), BIG, jnp.float32))
+                border_row = jnp.where(jnp.arange(Ly + 1)[None] == 0, 0.0,
+                                       jnp.full((B, Ly + 1), BIG, jnp.float32))
 
         lens = jnp.stack([lx, ly], axis=1).astype(jnp.int32)  # (B, 2)
         eps_col = eps_v[:, None]
-        args = [x_pad, y_rev_pad, gap_x, gap_y_rev, border_col, border_row,
-                lens, eps_col]
+        args = [x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
+                eps_col]
         if exec_mode == "scan":
             # compiled lax.scan twin: same layout, same per-diagonal math,
             # no batch blocking or banding (XLA owns the schedule)
@@ -454,8 +478,8 @@ class KernelSpec:
 
     def _cached(self, xs, ys, P, block_b, interpret, exec_mode=None,
                 tile=None):
-        key = (self.name, xs.shape[1:], str(xs.dtype), ys.shape[1:],
-               str(ys.dtype), P, block_b, interpret, exec_mode, tile)
+        key = CacheKey(self.name, xs.shape[1:], str(xs.dtype), ys.shape[1:],
+                       str(ys.dtype), P, block_b, interpret, exec_mode, tile)
         fn = _JIT_CACHE.get(key)
         if fn is None:
             spec = self

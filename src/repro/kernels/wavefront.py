@@ -13,8 +13,9 @@ distance).  The TPU-native schedule:
   diagonal buffers, an elementwise cost slice, min/add;
 * the elementwise cost is computed **on the fly** from the x tile and a
   *flipped* y tile: cost of diagonal k is ``elem(x[i-1], y[k-i-1])`` which
-  is a contiguous ``dynamic_slice`` of reversed-y — no gathers, no (L x L)
-  cost tile in HBM, arithmetic intensity stays on-chip;
+  is one contiguous window load of reversed-y at a sublane offset — no
+  gathers, no (L x L) cost tile in HBM, arithmetic intensity stays
+  on-chip;
 * per band, only that band's ``(Lx + tile)``-wide window of reversed-y is
   staged (``band_layout`` pre-gathers the overlapping windows, since a
   BlockSpec index map can only address multiples of the block shape), so
@@ -70,11 +71,36 @@ from jax.experimental.pallas import tpu as pltpu
 BIG = 3.4e37  # python float: Pallas kernels must not capture traced constants
 
 
+def _tiles(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def vmem_bytes(Lx: int, Ly: int, d: int, tile: int, block_b: int = 8) -> int:
+    """Upper bound on the banded kernel's VMEM working set, as the chip lays
+    it out: a block's last axis is padded to 128 lanes and the one before
+    it to 8 sublanes, so a ``(block_b, W, d)`` block costs ``W*128`` words
+    per row however small ``d`` is.  Counts the double-buffered x and
+    band-y blocks (y with one ERP gap channel), four in-loop temporaries
+    of the diagonal window's shape, the double-buffered 2-D operands and
+    outputs, and the carry scratch.  Only the y band grows with ``tile``.
+    """
+    W = Lx + 1
+    lanes_x = _tiles(d, 128)
+    lanes_y = _tiles(d + 1, 128)
+    rows = _tiles(block_b, 8)
+    words = (2 * block_b * _tiles(W, 8) * lanes_x
+             + 2 * block_b * _tiles(Lx + tile, 8) * lanes_y
+             + 4 * block_b * _tiles(W, 8) * lanes_y
+             + 2 * rows * (2 * _tiles(W, 128) + _tiles(Ly + 1, 128) + 5 * 128)
+             + rows * (2 * _tiles(W, 128) + 2 * 128))
+    return 4 * words
+
+
 def _shift_right(v, fill):
     return jnp.concatenate([jnp.full_like(v[:, :1], fill), v[:, :-1]], axis=1)
 
 
-def _make_step(mode: str, Lx: int, Ly: int):
+def _make_step(mode: str, Lx: int, Ly: int, d: int):
     """One anti-diagonal DP update — the single source of the per-step math.
 
     Every execution mode (tiled Pallas, interpret-mode Pallas, compiled
@@ -82,14 +108,21 @@ def _make_step(mode: str, Lx: int, Ly: int):
     the parity gates compare equality, not tolerance.  ``carry`` is
     ``(d1, d2, res, alive)``: the two rolling diagonals, the recorded
     answers, and the fused-ε liveness mask (f32 0/1 so it can ride VMEM
-    scratch).  ``ysl``/``gy`` are diagonal ``k``'s reversed-y window and
-    ERP gap window, already sliced by the caller (full-layout or band-tile
-    offsets — the only thing that differs between execution modes).
+    scratch).  ``yw`` is diagonal ``k``'s ``(B, Lx+1, dy)`` reversed-y
+    window, already sliced by the caller (full-layout or band-tile offsets
+    — the only thing that differs between execution modes); for ERP its
+    last channel is the reversed gap cost of y (``dy = d + 1``).
+
+    Every lookup by the traced diagonal index ``k`` is an iota select (and
+    a select-sum over one nonzero term, which is exact): Mosaic lowers
+    neither scatters nor value-level dynamic slices.
     """
 
-    def step(k, carry, x, ysl, gx, gy, bc, br, lx, target, eps, ii):
+    def step(k, carry, x, yw, gx, bc, br, lx, target, eps):
         d1, d2, res, alive = carry  # diagonals k-1, k-2
-        Bt = x.shape[0]
+        ii = jax.lax.broadcasted_iota(jnp.int32, (1, Lx + 1), 1)
+        jj = jax.lax.broadcasted_iota(jnp.int32, (1, Ly + 1), 1)
+        ysl = yw[..., :d]
         if mode == "lev":
             c = (jnp.sum(jnp.abs(x - ysl), axis=-1) > 0).astype(jnp.float32)
         else:
@@ -105,15 +138,16 @@ def _make_step(mode: str, Lx: int, Ly: int):
         elif mode == "lev":
             new = jnp.minimum(dd + c, jnp.minimum(du + 1.0, dl + 1.0))
         else:  # erp
+            gy = jnp.sum(yw[..., d:], axis=-1)
             new = jnp.minimum(dd + c, jnp.minimum(du + gx, dl + gy))
         # clamp: sums of quasi-infinities must stay quasi-infinite, never
         # run off to float32 inf/NaN (long high-gap-mass series)
         new = jnp.minimum(new, BIG)
         # border column j = 0 lives at position i = k (while k <= Lx)
-        colv = jax.lax.dynamic_slice(bc, (0, jnp.minimum(k, Lx)), (Bt, 1))
-        new = jnp.where((ii == k) & (k <= Lx), colv, new)
+        new = jnp.where((ii == k) & (k <= Lx), bc, new)
         # border row i = 0 lives at position 0 (while k <= Ly)
-        rowv = jax.lax.dynamic_slice(br, (0, jnp.minimum(k, Ly)), (Bt, 1))
+        rowv = jnp.sum(jnp.where(jj == jnp.minimum(k, Ly), br, 0.0),
+                       axis=1, keepdims=True)
         new = jnp.where(ii == 0, jnp.where(k <= Ly, rowv, BIG), new)
         # outside the valid band
         new = jnp.where((ii > k) | (ii < k - Ly), BIG, new)
@@ -129,6 +163,16 @@ def _make_step(mode: str, Lx: int, Ly: int):
     return step
 
 
+def _init_carry(bc, target):
+    """Diagonal 0 holds only ``D[0,0] = bc[:, 0]``; diagonal -1 is empty."""
+    ii = jax.lax.broadcasted_iota(jnp.int32, bc.shape, 1)
+    diag0 = jnp.where(ii == 0, bc, BIG)
+    return (diag0,
+            jnp.full(bc.shape, BIG, jnp.float32),
+            jnp.where(target == 0, bc[:, 0:1], BIG),
+            jnp.ones(target.shape, jnp.float32))
+
+
 def band_layout(y_rev_pad, Lx: int, Ly: int, tile: int):
     """Pre-gather the per-band overlapping reversed-y windows.
 
@@ -138,10 +182,12 @@ def band_layout(y_rev_pad, Lx: int, Ly: int, tile: int):
     ``o_j = Lx+1+Ly-(j+1)*tile``.  A BlockSpec index map can only address
     multiples of the block shape, so overlapping stride-``tile`` windows of
     width ``Lx + tile`` are not expressible directly — instead the bands
-    are gathered side by side into a ``(B, nbands*(Lx+tile)[, d])`` operand
-    whose ``j``-th slab is band ``j``'s tile, and the kernel's in-band
-    dynamic-slice offset for diagonal ``k`` is ``(j+1)*tile - k``
-    (``tile-1-t`` for the band-local step index ``t``).
+    are gathered into a ``(nbands, B, Lx+tile, dy)`` operand whose ``j``-th
+    slab is band ``j``'s tile.  The band axis leads so that a block's last
+    two dims are the full ``(Lx+tile, dy)`` (the TPU's (8, 128) block rule
+    holds at any band width), and the kernel's in-band offset for diagonal
+    ``k`` is ``(j+1)*tile - k`` (``tile-1-t`` for the band-local step
+    index ``t``) — on the sublane axis, where a dynamic offset is legal.
 
     Late bands clip below index 0; clipped positions are only ever read by
     DP cells outside the valid band, whose values the kernel overwrites
@@ -150,34 +196,29 @@ def band_layout(y_rev_pad, Lx: int, Ly: int, tile: int):
     Ypad = y_rev_pad.shape[1]
     K = Lx + Ly
     nbands = -(-K // tile)
-    Wb = Lx + tile
-    w = jnp.arange(Wb)
+    w = jnp.arange(Lx + tile)
     o = Lx + 1 + Ly - (jnp.arange(nbands) + 1) * tile
-    idx = jnp.clip(o[:, None] + w[None, :], 0, Ypad - 1).reshape(-1)
-    return jnp.take(y_rev_pad, idx, axis=1)
+    idx = jnp.clip(o[:, None] + w[None, :], 0, Ypad - 1)
+    return jnp.moveaxis(jnp.take(y_rev_pad, idx, axis=1), 1, 0)
 
 
 def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
                  nbands: int):
     W = Lx + 1
     K = Lx + Ly
-    step = _make_step(mode, Lx, Ly)
+    step = _make_step(mode, Lx, Ly, d)
 
-    def kernel(x_ref, yb_ref, gx_ref, gyb_ref, bc_ref, br_ref, lens_ref,
-               eps_ref, out_ref, hit_ref, prune_ref,
+    def kernel(x_ref, yb_ref, gx_ref, bc_ref, br_ref, lens_ref, eps_ref,
+               out_ref, hit_ref, prune_ref,
                d1_ref, d2_ref, res_ref, alive_ref):
         x = x_ref[...]          # (Bt, W, d)   x[i] = x_orig[i-1]
-        yb = yb_ref[...]        # (Bt, Lx+tile, d) this band's reversed-y tile
         gx = gx_ref[...]        # (Bt, W)      ERP gap cost of x_i (else 0)
-        gyb = gyb_ref[...]      # (Bt, Lx+tile) banded reversed ERP gap of y
         bc = bc_ref[...]        # (Bt, Lx+1)   border column D[i,0]
         br = br_ref[...]        # (Bt, Ly+1)   border row    D[0,j]
         lens = lens_ref[...]    # (Bt, 2)      int32 actual (len_x, len_y)
         eps = eps_ref[...]      # (Bt, 1)      fused threshold (+inf = off)
-        Bt = x.shape[0]
         lx = lens[:, 0:1]
         target = lx + lens[:, 1:2]   # diagonal holding D[len_x, len_y]
-        ii = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
         j = pl.program_id(1)
 
         # band 0 seeds the carry scratch; later bands inherit band j-1's
@@ -185,24 +226,18 @@ def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
         # scratch accumulators persist across a batch block's grid cells)
         @pl.when(j == 0)
         def _init():
-            diag0 = jnp.full((Bt, W), BIG,
-                             jnp.float32).at[:, 0].set(bc[:, 0])
-            d1_ref[...] = diag0
-            d2_ref[...] = jnp.full((Bt, W), BIG, jnp.float32)
-            res_ref[...] = jnp.where(target == 0, diag0[:, 0:1], BIG)
-            alive_ref[...] = jnp.ones((Bt, 1), jnp.float32)
+            d1, d2, res, alive = _init_carry(bc, target)
+            d1_ref[...] = d1
+            d2_ref[...] = d2
+            res_ref[...] = res
+            alive_ref[...] = alive
 
         def body(t, carry):
             k = j * tile + 1 + t
-            # diagonal k's window inside this band's tile (see band_layout)
-            off = tile - 1 - t
-            ysl = jax.lax.dynamic_slice(yb, (0, off, 0), (Bt, W, d))
-            if mode == "erp":
-                gy = jax.lax.dynamic_slice(gyb, (0, off), (Bt, W))
-            else:
-                gy = gx
-            out = step(k, carry, x, ysl, gx, gy, bc, br, lx, target, eps,
-                       ii)
+            # diagonal k's window inside this band's tile (see band_layout):
+            # yb_ref is (Bt, Lx+tile, dy), the offset rides the sublanes
+            yw = yb_ref[:, pl.ds(tile - 1 - t, W), :]
+            out = step(k, carry, x, yw, gx, bc, br, lx, target, eps)
             # the last band may be ragged: steps past diagonal K are no-ops
             return jax.tree_util.tree_map(
                 lambda n, o: jnp.where(k <= K, n, o), out, carry)
@@ -226,18 +261,21 @@ def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
     return kernel
 
 
-def wavefront_pallas(x_pad, y_rev_pad, gap_x, gap_y_rev, border_col,
-                     border_row, lens, eps, *, mode, Lx, Ly, d, block_b,
-                     interpret, tile: Optional[int] = None):
+def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
+                     eps, *, mode, Lx, Ly, d, block_b, interpret,
+                     tile: Optional[int] = None):
     """Run the banded kernel on pre-laid-out inputs (traceable — the
     registry owns jit caching; see ``registry.KernelSpec.device_call``).
 
     ``tile`` is the band depth in anti-diagonals (static per shape; the
     registry's ``default_tile`` VMEM-budget heuristic picks it when None).
     ``tile >= Lx + Ly`` degenerates to a single band — the exact untiled
-    schedule.  Returns ``(dist, hit, pruned)`` as (B,) float32 arrays:
-    masked distances (``BIG`` where the verdict is a miss), the hit mask,
-    and the early-prune certificate mask.
+    schedule.  The scoped-VMEM limit is set from :func:`vmem_bytes` (plus
+    1 MiB for the compiler's own scratch), so a band that the registry's
+    budget admitted, or an explicit ``tile``, is not refused by a fixed
+    default limit.  Returns ``(dist, hit, pruned)`` as (B,) float32
+    arrays: masked distances (``BIG`` where the verdict is a miss), the
+    hit mask, and the early-prune certificate mask.
     """
     B = x_pad.shape[0]
     W = Lx + 1
@@ -245,47 +283,42 @@ def wavefront_pallas(x_pad, y_rev_pad, gap_x, gap_y_rev, border_col,
     T = K if tile is None else max(1, min(int(tile), K))
     nbands = -(-K // T)
     Wb = Lx + T
-    y_bands = band_layout(y_rev_pad, Lx, Ly, T)    # (B, nbands*Wb, d)
-    gy_bands = band_layout(gap_y_rev, Lx, Ly, T)   # (B, nbands*Wb)
+    dy = y_rev_pad.shape[2]
+    y_bands = band_layout(y_rev_pad, Lx, Ly, T)    # (nbands, B, Wb, dy)
     grid = (B // block_b, nbands)
     kernel = _make_kernel(mode, Lx, Ly, d, T, nbands)
+    row = lambda b, j: (b, 0)  # noqa: E731 — per-batch-block 2-D operands
     outs = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_b, W, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((block_b, Wb, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((block_b, W), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, Wb), lambda b, j: (b, j)),
-            pl.BlockSpec((block_b, Lx + 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, Ly + 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, 2), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((pl.Squeezed(), block_b, Wb, dy),
+                         lambda b, j: (j, b, 0, 0)),
+            pl.BlockSpec((block_b, W), row),
+            pl.BlockSpec((block_b, Lx + 1), row),
+            pl.BlockSpec((block_b, Ly + 1), row),
+            pl.BlockSpec((block_b, 2), row),
+            pl.BlockSpec((block_b, 1), row),
         ],
-        out_specs=[
-            pl.BlockSpec((block_b, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, 1), lambda b, j: (b, 0)),
-            pl.BlockSpec((block_b, 1), lambda b, j: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((block_b, 1), row)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.float32)] * 3,
         scratch_shapes=[
             pltpu.VMEM((block_b, W), jnp.float32),   # carry diagonal k-1
             pltpu.VMEM((block_b, W), jnp.float32),   # carry diagonal k-2
             pltpu.VMEM((block_b, 1), jnp.float32),   # recorded answers
             pltpu.VMEM((block_b, 1), jnp.float32),   # fused-ε liveness
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(Lx, Ly, d, T, block_b) + (1 << 20)),
         interpret=interpret,
-    )(x_pad, y_bands, gap_x, gy_bands, border_col, border_row, lens, eps)
+    )(x_pad, y_bands, gap_x, border_col, border_row, lens, eps)
     dist, hit, pruned = outs
     return dist[:, 0], hit[:, 0] > 0, pruned[:, 0] > 0
 
 
-def wavefront_scan(x_pad, y_rev_pad, gap_x, gap_y_rev, border_col,
-                   border_row, lens, eps, *, mode, Lx, Ly, d):
+def wavefront_scan(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
+                   eps, *, mode, Lx, Ly, d):
     """Compiled ``lax.scan`` wavefront — the registry's ``exec="scan"``
     execution mode.
 
@@ -299,29 +332,19 @@ def wavefront_scan(x_pad, y_rev_pad, gap_x, gap_y_rev, border_col,
     """
     B = x_pad.shape[0]
     W = Lx + 1
+    dy = y_rev_pad.shape[2]
     lx = lens[:, 0:1]
     target = lx + lens[:, 1:2]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    step = _make_step(mode, Lx, Ly)
-
-    diag0 = jnp.full((B, W), BIG, jnp.float32).at[:, 0].set(border_col[:, 0])
-    carry0 = (diag0,
-              jnp.full((B, W), BIG, jnp.float32),
-              jnp.where(target == 0, diag0[:, 0:1], BIG),
-              jnp.ones((B, 1), jnp.float32))
+    step = _make_step(mode, Lx, Ly, d)
 
     def body(carry, k):
         s = Lx + 1 + Ly - k  # start of the diagonal window in reversed y
-        ysl = jax.lax.dynamic_slice(y_rev_pad, (0, s, 0), (B, W, d))
-        if mode == "erp":
-            gy = jax.lax.dynamic_slice(gap_y_rev, (0, s), (B, W))
-        else:
-            gy = gap_x
-        return step(k, carry, x_pad, ysl, gap_x, gy, border_col,
-                    border_row, lx, target, eps, ii), None
+        yw = jax.lax.dynamic_slice(y_rev_pad, (0, s, 0), (B, W, dy))
+        return step(k, carry, x_pad, yw, gap_x, border_col, border_row,
+                    lx, target, eps), None
 
     (_, _, res, alive), _ = jax.lax.scan(
-        body, carry0, jnp.arange(1, Lx + Ly + 1))
+        body, _init_carry(border_col, target), jnp.arange(1, Lx + Ly + 1))
     hit = res <= eps
     dist = jnp.where(hit, res, BIG)
     return dist[:, 0], hit[:, 0] > 0, alive[:, 0] < 0.5

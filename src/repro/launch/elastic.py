@@ -135,7 +135,8 @@ class ElasticIndex:
     def __init__(self, dist, data: np.ndarray, workers: List[str],
                  *, eps_prime: float = 1.0, tight_bounds: bool = True,
                  backend: str = "numpy", max_cohort: int = 256,
-                 interpret: bool = True, fleet_mode: str = "rounds",
+                 interpret: Optional[bool] = None,
+                 fleet_mode: str = "rounds",
                  lb_cascade="off", kernel_exec=None, kernel_tile=None):
         from repro.core import _deprecation
         from repro.distances import base as dist_base

@@ -18,30 +18,36 @@ round), a mid-load ``resize()`` runs through the zero-downtime
 snapshot-swap path, and every answer is cross-checked against the host
 per-shard oracle loop.  Latency lands as p50/p95/p99 percentiles.
 
-Timing methodology: an UNTIMED warmup batch runs first, so the timed
-section measures warm serving — first-call trace/compile never pollutes
-the reported qps (``traces_timed`` in the output counts kernel traces
-inside the timed window; warm serving keeps it at zero).
+Timing methodology: an UNTIMED warmup batch over every distinct query
+runs first, so the timed section measures warm serving — first-call
+trace/compile never pollutes the reported qps (``traces_timed`` in the
+output counts kernel traces inside the timed window; warm serving keeps it
+at zero).  :func:`serve_open_loop` is that warmup-then-serve window, shared
+with ``chip_smoke.py``.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import pathlib
 import time
+from typing import Optional
 
 import numpy as np
 
 from repro.data import synthetic
 from repro.kernels import registry as kernel_registry
+from repro.launch import compile_cache
 from repro.retrieval import RetrievalConfig, Retriever
 from repro.serve import OpenLoopLoadGen
 
 
 def build_config(args) -> RetrievalConfig:
     """``--config path.json`` round-trips the declarative config; otherwise
-    the legacy flags assemble the same dataclass."""
+    the legacy flags assemble the same dataclass, serving from the device
+    kernels (compiled on a TPU, interpreted elsewhere)."""
     if args.config:
         cfg = RetrievalConfig.from_json(
             pathlib.Path(args.config).read_text())
@@ -55,6 +61,7 @@ def build_config(args) -> RetrievalConfig:
         distance=args.distance or default_dist or "erp",
         execution="fleet",
         workers=[f"worker{i}" for i in range(args.shards)],
+        kernel_backend="pallas",
         tight_bounds=True)
 
 
@@ -68,6 +75,64 @@ def make_queries(data: np.ndarray, n: int, rng) -> np.ndarray:
         queries += rng.normal(scale=0.1, size=queries.shape).astype(
             queries.dtype)
     return queries
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """One open-loop serving window: the requests (in submission order),
+    the engine that served them, and the window's wall time and kernel
+    traces (0 when the warmup covered every shape)."""
+    requests: list
+    engine: object
+    serve_s: float
+    traces_timed: int
+    resized: bool
+
+
+def serve_open_loop(fleet, queries, eps: float, qps: float,
+                    n_requests: Optional[int] = None,
+                    resize_to: int = 0) -> ServeRun:
+    """Serve ``n_requests`` (``queries`` cycled) on an open-loop Poisson
+    schedule through ``fleet.serve(eps)``.
+
+    An UNTIMED warmup batch over every distinct query runs first, so the
+    timed window measures warm serving.  ``resize_to`` (a worker count
+    other than the current one; 0 = none) reshards mid-load through the
+    zero-downtime snapshot-swap path."""
+    workers = fleet.elastic().workers
+    n_requests = len(queries) if n_requests is None else n_requests
+    qlist = [queries[i % len(queries)] for i in range(n_requests)]
+
+    fleet.batch(queries).range(eps)
+    traces0 = kernel_registry.STATS["traces"]
+
+    engine = fleet.serve(eps).start()
+    load = OpenLoopLoadGen(engine, qlist, qps, eps=eps).start()
+    t0 = time.time()
+    resized = bool(resize_to) and resize_to != len(workers)
+    if resized:
+        # mid-load: snapshot -> reshard a clone off-path -> swap at a
+        # round boundary; the stream keeps serving throughout
+        time.sleep(0.5 / qps)
+        new_workers = (workers[:resize_to] if resize_to < len(workers)
+                       else workers + [f"w{i}" for i in
+                                       range(resize_to - len(workers))])
+        engine.resize(new_workers, block=False)
+    reqs = load.join()
+    if resized:
+        deadline = time.time() + 60
+        while engine.swaps == 0 and time.time() < deadline:
+            time.sleep(1e-3)
+    engine.close(drain=True)
+    serve_s = time.time() - t0
+    return ServeRun(reqs, engine, serve_s,
+                    kernel_registry.STATS["traces"] - traces0, resized)
+
+
+def latency_ms(engine) -> dict:
+    """The engine's p50/p95/p99 request latencies, in milliseconds."""
+    lat = engine.latency_stats()
+    return {f"latency_{k}_ms": 1e3 * lat[k] for k in ("p50", "p95", "p99")}
 
 
 def main():
@@ -94,6 +159,7 @@ def main():
                     help="mid-load zero-downtime resize to this many "
                          "workers (-1 = one fewer than built; 0 = skip)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     config = build_config(args)
     if args.snapshot_dir:
@@ -110,46 +176,22 @@ def main():
     queries = make_queries(data, args.queries, rng)
     n_requests = len(queries) if args.duration is None \
         else max(1, int(args.qps * args.duration))
-    qlist = [queries[i % len(queries)] for i in range(n_requests)]
 
     # oracle BEFORE serving: the host per-shard loop in ONE facade batch
     # call (hit sets are shard-layout-invariant, so it stays valid across
     # the mid-load resize below)
     oracle = fleet.batch(queries).via("host").range(args.eps).hits
 
-    # UNTIMED warmup: compile/trace every kernel shape the serve path hits,
-    # so the timed section below measures warm serving only
-    fleet.batch(queries[:2]).range(args.eps)
-    traces0 = kernel_registry.STATS["traces"]
-
-    engine = fleet.serve(args.eps).start()
-    load = OpenLoopLoadGen(engine, qlist, args.qps, eps=args.eps).start()
-    t0 = time.time()
-    resize_to = (len(workers) - 1 if args.resize_to == -1
-                 else args.resize_to)
-    did_resize = False
-    if resize_to and resize_to != len(workers):
-        # mid-load: snapshot -> reshard a clone off-path -> swap at a
-        # round boundary; the stream keeps serving throughout
-        time.sleep(0.5 / args.qps)
-        new_workers = (workers[:resize_to] if resize_to < len(workers)
-                       else workers + [f"w{i}" for i in
-                                       range(resize_to - len(workers))])
-        engine.resize(new_workers, block=False)
-        did_resize = True
-    reqs = load.join()
-    if did_resize:
-        deadline = time.time() + 60
-        while engine.swaps == 0 and time.time() < deadline:
-            time.sleep(1e-3)
-    engine.close(drain=True)
-    serve_s = time.time() - t0
-    traces_timed = kernel_registry.STATS["traces"] - traces0
+    run = serve_open_loop(
+        fleet, queries, args.eps, args.qps, n_requests,
+        resize_to=(len(workers) - 1 if args.resize_to == -1
+                   else args.resize_to))
+    reqs, engine = run.requests, run.engine
 
     mismatched = [i for i, r in enumerate(reqs)
                   if not r.done or r.hits != oracle[i % len(queries)]]
     assert not mismatched, f"serving drifted from oracle: {mismatched}"
-    if did_resize:
+    if run.resized:
         assert engine.swaps == 1, "snapshot-swap resize did not complete"
         post = [engine.submit(q) for q in queries]
         engine.start()
@@ -166,15 +208,13 @@ def main():
         "windows": len(data), "shards": len(workers),
         "build_s": round(build_s, 2),
         "requests": len(reqs),
-        "serve_s": round(serve_s, 3),
-        "warm_qps": round(len(reqs) / serve_s, 1),
-        "traces_timed": traces_timed,
+        "serve_s": round(run.serve_s, 3),
+        "warm_qps": round(len(reqs) / run.serve_s, 1),
+        "traces_timed": run.traces_timed,
         "merged_rounds": stats["rounds"],
         "mean_rounds_per_request": lat.get("mean_rounds"),
         "swaps": stats["swaps"],
-        "latency_p50_ms": round(1e3 * lat["p50"], 2),
-        "latency_p95_ms": round(1e3 * lat["p95"], 2),
-        "latency_p99_ms": round(1e3 * lat["p99"], 2),
+        **{k: round(v, 2) for k, v in latency_ms(engine).items()},
         "queue_p50_ms": round(1e3 * lat.get("queue_p50", 0.0), 2),
         "hits": sum(len(r.hits) for r in reqs),
         "query_evals": evals["query"],

@@ -52,7 +52,9 @@ mv_refs
 bulk_build     build hierarchies through the PR-2 cohort loader (default);
                ``False`` = sequential Alg.-1 inserts (legacy counts)
 max_cohort     cohort size cap for the bulk loader / fleet shard builds
-interpret      run Pallas kernels in interpret mode (off-TPU)
+interpret      run Pallas kernels in interpret mode; ``None`` (default)
+               follows the platform (``registry.default_interpret()``:
+               compiled on a TPU, interpreted elsewhere)
 serve_*        continuous-batching serve engine (``Retriever.serve()``,
                PR 9): ``serve_max_inflight`` caps concurrently in-flight
                requests, ``serve_admission`` picks the admission policy
@@ -99,7 +101,7 @@ class RetrievalConfig:
     mv_refs: int = 5
     bulk_build: bool = True
     max_cohort: int = 256
-    interpret: bool = True
+    interpret: Optional[bool] = None
     serve_max_inflight: int = 32
     serve_admission: str = "tick"
     serve_snapshot_dir: Optional[str] = None

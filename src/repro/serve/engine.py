@@ -94,7 +94,7 @@ class ServeEngine:
         self.queue = RequestQueue()
         evaluate, fused = fleet._round_evaluator()
         # ONE long-lived engine: the evaluator closes over the distance
-        # name + interpret flag only (shape-generic), so it keeps serving
+        # name + kernel policy only (shape-generic), so it keeps serving
         # across fleet swaps
         self._engine = FleetBatchEngine(evaluate, fused=fused)
         #: bid -> (request, per-group gids captured at admit time)

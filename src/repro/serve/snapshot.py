@@ -121,7 +121,7 @@ class FleetSnapshotManager:
                 "workers": list(fleet.workers),
                 "eps_prime": fleet.eps_prime, "tight": fleet.tight,
                 "backend": fleet.backend, "max_cohort": fleet.max_cohort,
-                "interpret": fleet.interpret, "fleet_mode": fleet.fleet_mode,
+                "fleet_mode": fleet.fleet_mode,
                 "lb_cascade": fleet.lb_cascade,
                 "kernel_exec": fleet.kernel_exec,
                 "kernel_tile": fleet.kernel_tile,
@@ -171,7 +171,9 @@ class FleetSnapshotManager:
         fleet.tight = meta["tight"]
         fleet.backend = meta["backend"]
         fleet.max_cohort = meta["max_cohort"]
-        fleet.interpret = meta["interpret"]
+        # interpret mode is the restoring platform's choice, never the
+        # snapshot's: a clone restored on a TPU runs compiled kernels
+        fleet.interpret = None
         fleet.fleet_mode = meta["fleet_mode"]
         fleet.lb_cascade = meta["lb_cascade"]
         # absent in pre-PR-10 snapshots: fall back to the registry policy

@@ -91,6 +91,31 @@ def test_snapshot_latest_and_retention(tmp_path):
     assert clone.workers == fleet.workers
 
 
+def test_interpret_follows_the_platform_through_defaults_and_restore(
+        tmp_path):
+    """No stored default pins interpret mode: the config and the fleet
+    default to None (the registry's platform policy: compiled on a TPU),
+    the JSON round-trip keeps it, and a snapshot of a fleet pinned to
+    interpret mode restores to a clone that follows the platform."""
+    import inspect
+
+    from repro.retrieval import RetrievalConfig
+    cfg = RetrievalConfig("levenshtein")
+    assert cfg.interpret is None
+    assert RetrievalConfig.from_json(cfg.to_json()).interpret is None
+    assert RetrievalConfig.from_json(
+        cfg.replace(interpret=False).to_json()).interpret is False
+    assert inspect.signature(ElasticIndex).parameters[
+        "interpret"].default is None
+
+    data, fleet = _fleet(n=60, workers=("a", "b"), interpret=True)
+    snap = FleetSnapshotManager(tmp_path)
+    clone = snap.restore(snap.save(fleet, block=True))
+    assert clone.interpret is None
+    qs = data[[4, 30]]
+    assert clone.range_query_batch(list(qs), 2.0) == _oracle(fleet, qs, 2.0)
+
+
 def test_restore_then_resize_shrink_and_grow(tmp_path):
     """A restored clone reshards exactly like the original would have:
     the shrink path (Alg.-2 deletes + masking) and the grow/append path

@@ -64,15 +64,16 @@ def test_band_layout_windows_match_full_slices():
     rng = np.random.default_rng(0)
     Lx, Ly, T = 7, 6, 3
     Ypad = 2 * Lx + Ly + 1
-    y = rng.normal(size=(2, Ypad)).astype(np.float32)
+    y = rng.normal(size=(2, Ypad, 3)).astype(np.float32)
     bands = np.asarray(band_layout(y, Lx, Ly, T))
     K = Lx + Ly
     nbands = -(-K // T)
     Wb = Lx + T
-    assert bands.shape == (2, nbands * Wb)
+    # band axis leads: every block's last two dims are the full (Wb, d)
+    assert bands.shape == (nbands, 2, Wb, 3)
     for j in range(nbands):
         o = Lx + 1 + Ly - (j + 1) * T
-        tile_j = bands[:, j * Wb:(j + 1) * Wb]
+        tile_j = bands[j]
         lo = max(0, o)
         np.testing.assert_array_equal(
             tile_j[:, lo - o:], y[:, lo:o + Wb],
