@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or not t["devices"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
