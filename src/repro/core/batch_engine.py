@@ -66,6 +66,7 @@ from typing import Dict, Generator, List, Optional, Sequence, Set
 
 import numpy as np
 
+from repro import spans
 from repro.core.counter import BUILD, QUERY, CountedDistance
 from repro.distances import bounds
 
@@ -253,9 +254,9 @@ class ShardPlans:
     ``plans[i]`` is a range-query plan over this shard's *local* database
     (frontier idxs index ``data``); ``queries`` holds one padded query row
     per plan with ``q_lens`` giving the actual lengths (ragged batches share
-    one padded width across the whole fleet).  ``shard`` is the provenance
-    id (the fleet worker slot) that rides every evaluated row into the
-    packed dispatcher's per-shard accounting.  ``lb`` optionally overrides
+    one padded width across the whole fleet).  ``shard`` is the fleet
+    worker slot, the key of the envelope hook's per-shard envelopes.
+    ``lb`` optionally overrides
     the engine-wide envelope hook for this group's rows — the serve layer
     uses it so requests admitted before and after a fleet swap each screen
     against the envelopes of the fleet that admitted them."""
@@ -305,17 +306,17 @@ class FleetBatchEngine:
 
     Evaluation accounting is the caller's: the engine tallies
     ``exact_evals`` / ``verdict_evals`` (requested rows only — backend
-    padding never reaches it), per-shard row provenance in ``shard_rows``,
-    and the fused-prune certificate count, and the elastic layer folds
-    those into ``ElasticIndex.device_stats`` — never into the shards' host
-    counters, so the ``{query, build}`` buckets stay host-path currency.
+    padding never reaches it) and the fused-prune certificate count, and
+    the elastic layer folds those into ``ElasticIndex.device_stats`` —
+    never into the shards' host counters, so the ``{query, build}``
+    buckets stay host-path currency.
     Frontier sequences are identical to driving each plan sequentially, so
     total evaluations match the host per-shard loop row for row.
     """
 
     def __init__(self, evaluate, *, fused: bool = False, lb=None):
-        #: ``evaluate(xs, ys, lx, ly, eps_rows, shard_ids) -> (dists,
-        #: n_pruned)`` — one backend call per merged round
+        #: ``evaluate(xs, ys, lx, ly, eps_rows) -> (dists, n_pruned)`` —
+        #: one backend call per merged round
         self.evaluate = evaluate
         self.fused = fused
         #: optional envelope-cascade hook ``lb(shard, idxs, q, q_len) ->
@@ -329,7 +330,6 @@ class FleetBatchEngine:
         self.fused_pruned = 0
         self.lb_rows = 0
         self.lb_pruned = 0
-        self.shard_rows: Dict[int, int] = {}
         self._next_bid = 0
         self._admitted: Dict[int, _Admitted] = {}
         self._state: Dict[tuple, Frontier] = {}   # (bid, g, i) -> frontier
@@ -385,91 +385,109 @@ class FleetBatchEngine:
                 if only is None or k[0] in only]
         if not keys:
             return []
+        with spans.span(spans.FLEET_ROUND, parts=len(keys)) as rnd:
+            parts = [(self._admitted[k[0]], k[1], k[2], self._state[k])
+                     for k in keys]
+            keeps, lbs = self._screen(parts)
+            xs, ys, lx, ly, verdict, eps_rows = self._gather(parts, keeps)
+            rnd.set_metadata(rows=len(xs))
+            if len(xs):
+                with spans.span(spans.FLEET_EVALUATE):
+                    ds, n_pruned = self.evaluate(
+                        xs, ys, lx, ly, eps_rows if self.fused else None)
+                    ds = np.asarray(ds, np.float32)
+            else:  # every row of the round was envelope-pruned
+                ds, n_pruned = np.zeros(0, np.float32), 0
+            self.rounds += 1
+            self.exact_evals += int((~verdict).sum())
+            self.verdict_evals += int(verdict.sum())
+            self.fused_pruned += int(n_pruned)
+            return self._resume(keys, parts, keeps, lbs, ds)
 
-        def _widen(parts):
+    def _screen(self, parts):
+        """The envelope tier over each shard's precomputed per-window
+        envelopes, on every VERDICT part: per part, the mask of rows that
+        enter the merged evaluate call and the bounds (None where no
+        bound ran) that answer the pruned ones."""
+        keeps, lbs = [], []
+        rows = pruned = 0
+        with spans.span(spans.FLEET_LB) as sp:
+            for batch, g, i, fr in parts:
+                grp = batch.groups[g]
+                m = fr.idxs.size
+                keep, lbv = np.ones(m, bool), None
+                hook = grp.lb if grp.lb is not None else self.lb
+                if hook is not None and fr.kind == VERDICT and m:
+                    lbv = np.asarray(
+                        hook(grp.shard, fr.idxs, grp.queries[i],
+                             int(grp.q_lens[i])), np.float32)
+                    keep = lbv <= batch.eps
+                    rows += m
+                    pruned += int(m - keep.sum())
+                keeps.append(keep)
+                lbs.append(lbv)
+            sp.set_metadata(lb_rows=rows, lb_pruned=pruned)
+        self.lb_rows += rows
+        self.lb_pruned += pruned
+        return keeps, lbs
+
+    @staticmethod
+    def _gather(parts, keeps):
+        """The round's kept rows, merged: query and window rows, their
+        lengths, which rows are VERDICT, and each row's ε (its batch's on
+        VERDICT rows, +inf on EXACT ones)."""
+        def _widen(rows):
             # batches admitted at different times pad their query rows
             # independently; harmonize widths before the concat (no-op —
             # and bit-identical — when one batch is in flight, i.e. run())
-            W = max(p.shape[1] for p in parts)
+            W = max(p.shape[1] for p in rows)
             return [p if p.shape[1] == W else
                     np.pad(p, ((0, 0), (0, W - p.shape[1]))
-                           + ((0, 0),) * (p.ndim - 2)) for p in parts]
+                           + ((0, 0),) * (p.ndim - 2)) for p in rows]
 
-        sizes = [self._state[k].idxs.size for k in keys]
-        xs_parts, ys_parts, lx_parts, ly_parts = [], [], [], []
-        shard_parts, verdict_parts = [], []
-        part_keep, part_lb = [], []  # per-part cascade masks / bounds
-        eps_parts = []               # per-row ε (each batch carries its own)
-        for k, m in zip(keys, sizes):
-            bid, g, i = k
-            batch = self._admitted[bid]
-            grp = batch.groups[g]
-            fr = self._state[k]
-            keep = np.ones(m, bool)
-            lbv = None
-            hook = grp.lb if grp.lb is not None else self.lb
-            if hook is not None and fr.kind == VERDICT and m:
-                # envelope tier over the shard's precomputed per-window
-                # envelopes: pruned rows answer with the bound below
-                # and never enter the merged evaluate call
-                lbv = np.asarray(
-                    hook(grp.shard, fr.idxs, grp.queries[i],
-                         int(grp.q_lens[i])), np.float32)
-                keep = lbv <= batch.eps
-                self.lb_rows += m
-                self.lb_pruned += int(m - keep.sum())
-            part_keep.append(keep)
-            part_lb.append(lbv)
-            mk = int(keep.sum())
-            xs_parts.append(np.repeat(grp.queries[i][None], mk, 0))
-            ys_parts.append(grp.data[fr.idxs[keep]])
-            lx_parts.append(np.full(mk, int(grp.q_lens[i]), np.int64))
-            ly_parts.append(np.full(mk, grp.data.shape[1], np.int64))
-            shard_parts.append(np.full(mk, grp.shard, np.int64))
-            verdict_parts.append(np.full(mk, fr.kind == VERDICT))
-            eps_parts.append(np.full(
-                mk, batch.eps if fr.kind == VERDICT else np.inf, np.float32))
-            self.shard_rows[grp.shard] = \
-                self.shard_rows.get(grp.shard, 0) + mk
-        xs = np.concatenate(_widen(xs_parts))
-        ys = np.concatenate(_widen(ys_parts))
-        lx = np.concatenate(lx_parts)
-        ly = np.concatenate(ly_parts)
-        shard_ids = np.concatenate(shard_parts)
-        verdict = np.concatenate(verdict_parts)
+        with spans.span(spans.FLEET_GATHER) as sp:
+            xs, ys, lx, ly, verdict, eps = [], [], [], [], [], []
+            for (batch, g, i, fr), keep in zip(parts, keeps):
+                grp = batch.groups[g]
+                mk = int(keep.sum())
+                xs.append(np.repeat(grp.queries[i][None], mk, 0))
+                ys.append(grp.data[fr.idxs[keep]])
+                lx.append(np.full(mk, int(grp.q_lens[i]), np.int64))
+                ly.append(np.full(mk, grp.data.shape[1], np.int64))
+                verdict.append(np.full(mk, fr.kind == VERDICT))
+                eps.append(np.full(
+                    mk, batch.eps if fr.kind == VERDICT else np.inf,
+                    np.float32))
+            out = (np.concatenate(_widen(xs)), np.concatenate(_widen(ys)),
+                   np.concatenate(lx), np.concatenate(ly),
+                   np.concatenate(verdict), np.concatenate(eps))
+            sp.set_metadata(rows=len(out[0]))
+        return out
 
-        if len(xs):
-            eps_rows = np.concatenate(eps_parts) if self.fused else None
-            ds, n_pruned = self.evaluate(xs, ys, lx, ly, eps_rows,
-                                         shard_ids)
-            ds = np.asarray(ds, np.float32)
-        else:  # every row of the round was envelope-pruned
-            ds, n_pruned = np.zeros(0, np.float32), 0
-        self.rounds += 1
-        self.exact_evals += int((~verdict).sum())
-        self.verdict_evals += int(verdict.sum())
-        self.fused_pruned += int(n_pruned)
-
+    def _resume(self, keys, parts, keeps, lbs, ds) -> List[int]:
+        """Send each plan its part of the round's answers (bounds on the
+        pruned rows), retire finished plans; returns finished batch ids."""
         finished: List[int] = []
         off = 0
-        for k, m, keep, lbv in zip(keys, sizes, part_keep, part_lb):
-            bid, g, i = k
-            batch = self._admitted[bid]
-            mk = int(keep.sum())
-            out = np.empty(m, np.float32)
-            if lbv is not None:
-                out[~keep] = lbv[~keep]
-            out[keep] = ds[off:off + mk]
-            try:
-                self._state[k] = batch.groups[g].plans[i].send(out)
-            except StopIteration as stop:
-                del self._state[k]
-                batch.results[g][i] = stop.value \
-                    if stop.value is not None else []
-                batch.live -= 1
-                if batch.live == 0:
-                    finished.append(bid)
-            off += mk
+        with spans.span(spans.FLEET_RESUME) as sp:
+            for k, (batch, g, i, fr), keep, lbv in zip(keys, parts, keeps,
+                                                        lbs):
+                mk = int(keep.sum())
+                out = np.empty(fr.idxs.size, np.float32)
+                if lbv is not None:
+                    out[~keep] = lbv[~keep]
+                out[keep] = ds[off:off + mk]
+                try:
+                    self._state[k] = batch.groups[g].plans[i].send(out)
+                except StopIteration as stop:
+                    del self._state[k]
+                    batch.results[g][i] = stop.value \
+                        if stop.value is not None else []
+                    batch.live -= 1
+                    if batch.live == 0:
+                        finished.append(k[0])
+                off += mk
+            sp.set_metadata(finished=len(finished))
         return finished
 
     # -- one-shot contract (admit once, drain) ------------------------------

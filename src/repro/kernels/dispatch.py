@@ -21,9 +21,9 @@ accounting stays with :class:`~repro.core.counter.CountedDistance`, which
 counts requested rows only (the same positional-masking discipline PR 3
 established for the device query path).
 
-:data:`STATS` tracks what per-bucket dispatch would have cost
-(``bucket_rounds``) against what packing actually paid (``dispatches``) —
-``benchmarks/bench_kernels.py`` gates the collapse.
+:data:`STATS` counts the packed calls issued and the rows they carried;
+the ``dispatch.*`` spans (:mod:`repro.spans`) time the pack, the padding,
+the launch, the copy back and the unpack of each call.
 """
 
 from __future__ import annotations
@@ -33,6 +33,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.kernels import registry
 
 
@@ -43,28 +44,17 @@ class PackedMeta:
     buckets: Tuple[Tuple[int, int, int], ...]
     #: row offset of each bucket in the sorted layout
     offsets: Tuple[int, ...]
-    #: ``(shard, count)`` row provenance when the dispatch spans a fleet
-    #: round (cross-shard frontier merge); None for single-source dispatches
-    shard_rows: Optional[Tuple[Tuple[int, int], ...]] = None
 
     @property
     def n_buckets(self) -> int:
         return len(self.buckets)
-
-    @property
-    def n_shards(self) -> int:
-        return len(self.shard_rows) if self.shard_rows else 1
 
 
 @dataclasses.dataclass
 class DispatchStats:
     """Cumulative packed-dispatch accounting (benchmarks read this)."""
     dispatches: int = 0     # packed device calls actually issued
-    bucket_rounds: int = 0  # calls a per-bucket dispatcher would have issued
     rows: int = 0           # requested rows (excl. any padding)
-    pruned: int = 0         # rows certified > eps before their last diagonal
-    #: rows per fleet shard across cross-shard (round-based fleet) dispatches
-    shard_rows: Dict[int, int] = dataclasses.field(default_factory=dict)
     #: LB-cascade accounting per tier (``endpoint`` / ``envelope``): rows a
     #: tier's bound was evaluated on, and rows it certified ``> eps``.
     #: Requested rows only — the registry's pow2 batch padding is sliced
@@ -75,10 +65,7 @@ class DispatchStats:
 
     def reset(self) -> None:
         self.dispatches = 0
-        self.bucket_rounds = 0
         self.rows = 0
-        self.pruned = 0
-        self.shard_rows = {}
         self.lb_rows = {}
         self.lb_pruned = {}
         self.last_meta = None
@@ -124,8 +111,8 @@ def pack_meta(lx: np.ndarray, ly: np.ndarray
 
 def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
                  block_b: int = 8, interpret: Optional[bool] = None,
-                 exec: Optional[str] = None, tile: Optional[int] = None,
-                 shards=None) -> registry.KernelOut:
+                 exec: Optional[str] = None, tile: Optional[int] = None
+                 ) -> registry.KernelOut:
     """ONE padded device call over every length bucket of a round.
 
     ``xs``/``ys`` are row-paired batches whose rows may come from different
@@ -133,12 +120,7 @@ def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
     ``eps`` (scalar or per-row; +inf rows opt out) enables fused ε-pruning.
     ``exec``/``tile`` pick the wavefront execution mode and Pallas band
     depth (None: the registry's process-wide policy / VMEM heuristic).
-    ``shards`` optionally carries per-row provenance (the fleet worker slot
-    each row's candidate window lives on) when a round-based fleet query
-    merges frontiers across shards — recorded in :data:`STATS` and
-    :class:`PackedMeta` so the benches can show a fleet round really is one
-    dispatch, not one per shard.  Results come back in the caller's row
-    order as numpy arrays.
+    Results come back in the caller's row order as numpy arrays.
     """
     spec = registry.get(name)
     xs = np.asarray(xs)
@@ -147,36 +129,28 @@ def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
     if B == 0:
         z = np.zeros((0,), np.float32)
         return registry.KernelOut(z, z.astype(bool), z.astype(bool))
-    lx = np.full(B, xs.shape[1], np.int64) if lx is None \
-        else np.asarray(lx, np.int64)
-    ly = np.full(B, ys.shape[1], np.int64) if ly is None \
-        else np.asarray(ly, np.int64)
-    eps_v = None if eps is None else \
-        np.broadcast_to(np.asarray(eps, np.float32), (B,))
+    with spans.span(spans.DISPATCH_PACK, rows=B) as sp:
+        lx = np.full(B, xs.shape[1], np.int64) if lx is None \
+            else np.asarray(lx, np.int64)
+        ly = np.full(B, ys.shape[1], np.int64) if ly is None \
+            else np.asarray(ly, np.int64)
+        eps_v = None if eps is None else \
+            np.broadcast_to(np.asarray(eps, np.float32), (B,))
+        order, meta = pack_meta(lx, ly)
+        sp.set_metadata(buckets=meta.n_buckets)
+        xs, ys, lx, ly = xs[order], ys[order], lx[order], ly[order]
+        if eps_v is not None:
+            eps_v = eps_v[order]
+    out = spec.batch(xs, ys, lx, ly, eps=eps_v, block_b=block_b,
+                     interpret=interpret, exec=exec, tile=tile)
 
-    order, meta = pack_meta(lx, ly)
-    out = spec.batch(
-        xs[order], ys[order], lx[order], ly[order],
-        eps=None if eps_v is None else eps_v[order],
-        block_b=block_b, interpret=interpret, exec=exec, tile=tile)
-
-    inv = np.empty_like(order)
-    inv[order] = np.arange(B)
-    result = registry.KernelOut(out.dist[inv], out.hit[inv], out.pruned[inv])
-
-    if shards is not None:
-        sid, cnt = np.unique(np.asarray(shards, np.int64),
-                             return_counts=True)
-        for s, c in zip(sid, cnt):
-            STATS.shard_rows[int(s)] = \
-                STATS.shard_rows.get(int(s), 0) + int(c)
-        meta = dataclasses.replace(
-            meta, shard_rows=tuple((int(s), int(c))
-                                   for s, c in zip(sid, cnt)))
+    with spans.span(spans.DISPATCH_UNPACK):
+        inv = np.empty_like(order)
+        inv[order] = np.arange(B)
+        result = registry.KernelOut(out.dist[inv], out.hit[inv],
+                                    out.pruned[inv])
     STATS.dispatches += 1
-    STATS.bucket_rounds += meta.n_buckets
     STATS.rows += B
-    STATS.pruned += int(result.pruned.sum())
     STATS.last_meta = meta
     return result
 

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.kernels.wavefront import (BIG, vmem_bytes, wavefront_pallas,
                                      wavefront_scan)
 
@@ -63,9 +64,9 @@ MODE_OF_NAME = {"dtw": "dtw", "erp": "erp", "frechet": "dfd",
                 "levenshtein": "lev"}
 NAME_OF_MODE = {v: k for k, v in MODE_OF_NAME.items()}
 
-#: trace/call accounting — ``traces`` increments once per kernel compile
-#: (the retrace regression tests pin it), ``calls`` once per host dispatch.
-STATS = {"traces": 0, "calls": 0}
+#: trace accounting — ``traces`` increments once per kernel compile (the
+#: retrace regression tests pin it).
+STATS = {"traces": 0}
 
 
 class CacheKey(NamedTuple):
@@ -205,7 +206,6 @@ def clear_cache() -> None:
     """Drop compiled kernels + stats (test hygiene)."""
     _JIT_CACHE.clear()
     STATS["traces"] = 0
-    STATS["calls"] = 0
 
 
 def cache_keys() -> list:
@@ -445,36 +445,44 @@ class KernelSpec:
         if B == 0:
             z = np.zeros((0,), np.float32)
             return KernelOut(z, z.astype(bool), z.astype(bool))
-        if lx is None:
-            lx = np.full(B, xs.shape[1], np.int32)
-        else:
-            lx = np.asarray(lx, np.int32)
-            xs = xs[:, :max(int(lx.max()), 1)]
-        if ly is None:
-            ly = np.full(B, ys.shape[1], np.int32)
-        else:
-            ly = np.asarray(ly, np.int32)
-            ys = ys[:, :max(int(ly.max()), 1)]
-        eps_v = np.full(B, np.inf, np.float32) if eps is None else \
-            np.broadcast_to(np.asarray(eps, np.float32), (B,))
-        interpret = resolve_interpret(interpret)
-        if self.kind == "wavefront":
-            exec_mode = resolve_exec(exec)
-            if exec_mode == "pallas" and tile is None:
-                dim = xs.shape[2] if xs.ndim == 3 else 1
-                tile = default_tile(xs.shape[1], ys.shape[1], dim, block_b)
-            if exec_mode == "scan":
-                tile = None  # scan has no banding: one cache entry per shape
-        else:
-            exec_mode, tile = None, None  # elementwise/envelope: pure jnp
-
-        P = _pad_pow2(max(B, block_b))
-        fn = self._cached(xs, ys, P, block_b, interpret, exec_mode, tile)
-        d, h, p = fn(_pad_rows(xs, P), _pad_rows(ys, P), _pad_rows(lx, P),
-                     _pad_rows(ly, P), _pad_rows(eps_v, P))
-        STATS["calls"] += 1
-        return KernelOut(np.asarray(d)[:B], np.asarray(h)[:B],
-                         np.asarray(p)[:B])
+        with spans.span(spans.DISPATCH_PAD, rows=B) as sp:
+            if lx is None:
+                lx = np.full(B, xs.shape[1], np.int32)
+            else:
+                lx = np.asarray(lx, np.int32)
+                xs = xs[:, :max(int(lx.max()), 1)]
+            if ly is None:
+                ly = np.full(B, ys.shape[1], np.int32)
+            else:
+                ly = np.asarray(ly, np.int32)
+                ys = ys[:, :max(int(ly.max()), 1)]
+            eps_v = np.full(B, np.inf, np.float32) if eps is None else \
+                np.broadcast_to(np.asarray(eps, np.float32), (B,))
+            P = _pad_pow2(max(B, block_b))
+            args = [_pad_rows(a, P) for a in (xs, ys, lx, ly, eps_v)]
+            sp.set_metadata(
+                padded_rows=P, cells=int(np.dot(lx.astype(np.int64), ly)),
+                padded_cells=P * xs.shape[1] * ys.shape[1])
+            interpret = resolve_interpret(interpret)
+            if self.kind == "wavefront":
+                exec_mode = resolve_exec(exec)
+                if exec_mode == "pallas" and tile is None:
+                    dim = xs.shape[2] if xs.ndim == 3 else 1
+                    tile = default_tile(xs.shape[1], ys.shape[1], dim,
+                                        block_b)
+                if exec_mode == "scan":
+                    tile = None  # no banding: one cache entry per shape
+            else:
+                exec_mode, tile = None, None  # elementwise/envelope: jnp
+        with spans.span(spans.DISPATCH_LAUNCH,
+                        h2d_bytes=sum(a.nbytes for a in args)):
+            fn = self._cached(xs, ys, P, block_b, interpret, exec_mode,
+                              tile)
+            d, h, p = fn(*args)
+        with spans.span(spans.DISPATCH_FETCH,
+                        d2h_bytes=d.nbytes + h.nbytes + p.nbytes):
+            return KernelOut(np.asarray(d)[:B], np.asarray(h)[:B],
+                             np.asarray(p)[:B])
 
     def _cached(self, xs, ys, P, block_b, interpret, exec_mode=None,
                 tile=None):

@@ -312,6 +312,7 @@ def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_bytes(Lx, Ly, d, T, block_b) + (1 << 20)),
         interpret=interpret,
+        name=f"wavefront_{mode}",
     )(x_pad, y_bands, gap_x, border_col, border_row, lens, eps)
     dist, hit, pruned = outs
     return dist[:, 0], hit[:, 0] > 0, pruned[:, 0] > 0

@@ -382,7 +382,7 @@ class ElasticIndex:
 
         On the ``pallas`` backend (with a registered kernel) a merged round
         goes straight through the packed ragged-bucket dispatcher with
-        per-row shard provenance and fused ε-pruning — the kernel returns
+        fused ε-pruning — the kernel returns
         the hit verdict and never materializes pruned candidates'
         distances.  Other backends evaluate the round in one host batch
         call (values still preserve every ``<= eps`` verdict)."""
@@ -392,12 +392,11 @@ class ElasticIndex:
         if self.backend == "pallas" and kernel_registry.has(self.dist.name):
             from repro.kernels.dispatch import packed_batch
 
-            def evaluate(xs, ys, lx, ly, eps_rows, shard_ids):
+            def evaluate(xs, ys, lx, ly, eps_rows):
                 out = packed_batch(self.dist.name, xs, ys, lx, ly,
                                    eps=eps_rows, interpret=self.interpret,
                                    exec=self.kernel_exec,
-                                   tile=self.kernel_tile,
-                                   shards=shard_ids)
+                                   tile=self.kernel_tile)
                 return (np.asarray(out.dist, np.float32),
                         int(np.asarray(out.pruned).sum()))
 
@@ -407,7 +406,7 @@ class ElasticIndex:
             batch = _resolve_backend(self.dist, self.backend,
                                      self.kernel_exec, self.kernel_tile)
 
-            def evaluate(xs, ys, lx, ly, eps_rows, shard_ids):
+            def evaluate(xs, ys, lx, ly, eps_rows):
                 return np.asarray(batch(xs, ys, lx, ly), np.float32), 0
 
             self._round_eval = (evaluate, False)

@@ -56,6 +56,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.batch_engine import FleetBatchEngine, ShardPlans
 from repro.serve.queue import Request, RequestQueue
 from repro.serve.snapshot import FleetSnapshotManager
@@ -147,24 +148,25 @@ class ServeEngine:
         return hook
 
     def _admit_one(self, req: Request, now: float) -> Optional[int]:
-        fleet = self.fleet
-        q = np.asarray(req.query)
-        qpad, q_lens = q[None], np.asarray([len(q)], np.int64)
-        hook = self._lb_hook(fleet)
-        groups: List[ShardPlans] = []
-        gids: List[np.ndarray] = []
-        for si, w in enumerate(fleet.workers):
-            s = fleet.shards.get(w)
-            if s is None:
-                continue
-            groups.append(ShardPlans(
-                shard=si, data=s.net.data,
-                plans=[s.net.range_query_plan(req.eps)],
-                queries=qpad, q_lens=q_lens, lb=hook))
-            gids.append(s.gids)
-        req.t_admit = now
-        bid = self._engine.admit(groups, req.eps)
-        self._inflight[bid] = (req, gids)
+        with spans.span(spans.SERVE_ADMIT, rid=req.rid):
+            fleet = self.fleet
+            q = np.asarray(req.query)
+            qpad, q_lens = q[None], np.asarray([len(q)], np.int64)
+            hook = self._lb_hook(fleet)
+            groups: List[ShardPlans] = []
+            gids: List[np.ndarray] = []
+            for si, w in enumerate(fleet.workers):
+                s = fleet.shards.get(w)
+                if s is None:
+                    continue
+                groups.append(ShardPlans(
+                    shard=si, data=s.net.data,
+                    plans=[s.net.range_query_plan(req.eps)],
+                    queries=qpad, q_lens=q_lens, lb=hook))
+                gids.append(s.gids)
+            req.t_admit = now
+            bid = self._engine.admit(groups, req.eps)
+            self._inflight[bid] = (req, gids)
         if self._engine.is_finished(bid):  # e.g. an empty fleet
             self._finalize(bid, now)
             return None
@@ -172,11 +174,13 @@ class ServeEngine:
 
     def _finalize(self, bid: int, now: float) -> Request:
         req, gids = self._inflight.pop(bid)
-        per_group = self._engine.results(bid)
-        hits = set()
-        for g, res in zip(gids, per_group):
-            hits.update(int(g[x]) for x in res[0])
-        req.finish(sorted(hits), now)
+        with spans.span(spans.SERVE_FINALIZE, rid=req.rid) as sp:
+            per_group = self._engine.results(bid)
+            hits = set()
+            for g, res in zip(gids, per_group):
+                hits.update(int(g[x]) for x in res[0])
+            sp.set_metadata(hits=len(hits))
+            req.finish(sorted(hits), now)
         self.completed.append(req)
         return req
 
@@ -198,7 +202,7 @@ class ServeEngine:
     def tick(self, now: Optional[float] = None) -> List[Request]:
         """One scheduler beat: swap -> admit -> (greedy round) -> shared
         round.  Returns the requests completed this tick."""
-        with self._lock:
+        with self._lock, spans.span(spans.SERVE_TICK) as sp:
             now = self.clock() if now is None else now
             if self._pending_swap is not None:  # round boundary: safe swap
                 self.fleet = self._pending_swap
@@ -207,10 +211,13 @@ class ServeEngine:
             had_inflight = bool(self._inflight)
             budget = self.config.max_inflight - len(self._inflight)
             newly: Set[int] = set()
-            for req in self.queue.take(max(budget, 0)):
+            taken = self.queue.take(max(budget, 0))
+            for req in taken:
                 bid = self._admit_one(req, now)
                 if bid is not None:
                     newly.add(bid)
+            sp.set_metadata(admitted=len(taken),
+                            inflight=len(self._inflight))
             done: List[Request] = []
             if self.config.admission == "greedy" and had_inflight and newly:
                 # dedicated first round: newcomers dispatch immediately

@@ -231,3 +231,116 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed (pip install .[dev])")
     def test_engine_parity_property():
         pass
+
+
+# -- FleetBatchEngine: a round's screen and gather stages --------------------
+
+
+def _reference_fleet_rounds(groups, eps, hook, batch):
+    """Drive one batch of fleet plans with the screen and the gather done
+    part by part in one loop; returns each round's evaluator operands, the
+    LB tallies and the per-group, per-plan results."""
+    from repro.core.batch_engine import VERDICT
+    state, results = {}, [[None] * len(g.plans) for g in groups]
+    for g, grp in enumerate(groups):
+        for i, p in enumerate(grp.plans):
+            try:
+                state[(g, i)] = next(p)
+            except StopIteration as stop:
+                results[g][i] = stop.value or []
+    rounds, lb_rows, lb_pruned = [], 0, 0
+    while state:
+        keys = sorted(state)
+        cols = [[] for _ in range(5)]
+        parts = []
+        for g, i in keys:
+            grp, fr = groups[g], state[(g, i)]
+            m = fr.idxs.size
+            keep, lbv = np.ones(m, bool), None
+            if hook is not None and fr.kind == VERDICT and m:
+                lbv = hook(grp.shard, fr.idxs, grp.queries[i],
+                           int(grp.q_lens[i]))
+                keep = lbv <= eps
+                lb_rows += m
+                lb_pruned += int(m - keep.sum())
+            mk = int(keep.sum())
+            for col, v in zip(cols, (
+                    np.repeat(grp.queries[i][None], mk, 0),
+                    grp.data[fr.idxs[keep]],
+                    np.full(mk, int(grp.q_lens[i])),
+                    np.full(mk, grp.data.shape[1]),
+                    np.full(mk, eps if fr.kind == VERDICT else np.inf,
+                            np.float32))):
+                col.append(v)
+            parts.append((keep, lbv))
+        ops = [np.concatenate(c) for c in cols]
+        rounds.append(ops)
+        ds = np.asarray(batch(*ops[:4]), np.float32) if len(ops[0]) else []
+        off = 0
+        for (g, i), (keep, lbv) in zip(keys, parts):
+            out = np.empty(keep.size, np.float32)
+            if lbv is not None:
+                out[~keep] = lbv[~keep]
+            out[keep] = ds[off:off + keep.sum()]
+            off += keep.sum()
+            try:
+                state[(g, i)] = groups[g].plans[i].send(out)
+            except StopIteration as stop:
+                del state[(g, i)]
+                results[g][i] = stop.value or []
+    return rounds, lb_rows, lb_pruned, results
+
+
+@pytest.mark.parametrize("dist_name", ["erp", "frechet"])
+@pytest.mark.parametrize("tier", ["off", "envelope"])
+def test_fleet_round_screen_and_gather_stages(tier, dist_name):
+    """Screening every part, then gathering every part, sends the
+    evaluator the rows, order and ε of the one-loop round, and tallies
+    the same LB counts."""
+    from repro.core.batch_engine import FleetBatchEngine, ShardPlans
+    from repro.distances import bounds, np_backend
+    rng = np.random.default_rng(5)
+    data = _series(90, rng=rng)
+    shards = np.array_split(data, 3)
+    qs = data[[4, 33, 71]] + rng.normal(scale=0.05, size=(3, 10, 2))
+    eps = 1.0
+    envs = [bounds.build_envelopes(s) for s in shards]
+
+    def hook(shard, idxs, q, q_len):
+        e = envs[shard].take(idxs)
+        return bounds.lb_envelope_rows(
+            dist_name, np.repeat(q[None], len(idxs), 0),
+            np.full(len(idxs), q_len), e.lo, e.hi, e.mass)
+
+    hook = hook if tier == "envelope" else None
+    batch = np_backend.batch_for(dist_name)
+    nets = [ReferenceNet(get(dist_name), s, eps_prime=1.0, num_max=4,
+                         tight_bounds=True).build() for s in shards]
+
+    def groups():
+        return [ShardPlans(shard=si, data=net.data,
+                           plans=[net.range_query_plan(eps) for _ in qs],
+                           queries=qs, q_lens=np.full(len(qs), 10))
+                for si, net in enumerate(nets)]
+
+    seen = []
+
+    def evaluate(xs, ys, lx, ly, eps_rows):
+        seen.append([xs, ys, lx, ly, eps_rows])
+        return batch(xs, ys, lx, ly), 0
+
+    engine = FleetBatchEngine(evaluate, fused=True, lb=hook)
+    got = engine.run(groups(), eps)
+    rounds, lb_rows, lb_pruned, want = _reference_fleet_rounds(
+        groups(), eps, hook, batch)
+    assert got == want
+    assert (engine.lb_rows, engine.lb_pruned) == (lb_rows, lb_pruned)
+    assert (lb_rows > 0) == (tier == "envelope")
+    assert engine.rounds == len(rounds)
+    evaluated = [r for r in rounds if len(r[0])]
+    assert len(seen) == len(evaluated)
+    for a, b in zip(seen, evaluated):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+    assert engine.exact_evals + engine.verdict_evals == sum(
+        len(r[0]) for r in rounds)
