@@ -34,6 +34,18 @@ Children are resolved lazily (objects at the very end, expandable references
 just before their own level), so every parent that gets processed
 contributes its bound before any distance evaluation is spent.
 
+The query plan runs on arrays, not on the node dicts: a
+:class:`PlanSnapshot` (levels, subtree radii, and per level a CSR of the
+lists with their link radii) is built on first use and cached until a
+method that changes the net (``insert``, ``build_batched``, ``delete``,
+``extend_data``) drops it, so one build serves every query until the next
+change.  The plan keeps its per-query state in arrays over the database
+rows and propagates bounds one level at a time with numpy; a subtree a
+bound settles is marked at its top only, and the mark is passed down each
+level's links just before the level below is visited, so no settled
+subtree is walked node by node.  That needs every list to hold nodes of
+lower levels only, which ``delete``'s re-homing keeps.
+
 All distance evaluations go through :class:`CountedDistance`, so pruning
 ratios reported by the benchmarks are exact evaluation counts.
 
@@ -89,6 +101,37 @@ class Node:
     sub_radius: float = 0.0    # exact derived-subtree radius (maintained)
 
 
+@dataclasses.dataclass(frozen=True)
+class PlanSnapshot:
+    """The net's structure as arrays, for
+    :meth:`ReferenceNet.range_query_plan`.
+
+    Per row of the net's database: the subtree radius as
+    ``_subtree_radius`` gives it and whether the row's node has a list
+    (rows that hold no node read 0 and False).  The lists form one CSR
+    per level: ``levels[l]`` holds the ids of the level's list holders in
+    ascending order, the offsets of each holder's links, and the links'
+    child ids and radii (``_link_radius``) in list order.  Links to
+    deleted nodes are left out.  Plain objects hold no list, and every
+    list holds nodes of lower levels only."""
+    rows: int
+    sub_radius: np.ndarray         # (rows,) float64
+    has_children: np.ndarray       # (rows,) bool
+    #: level (top_level .. 0) -> (holder ids, link offsets, child ids,
+    #: link radii)
+    levels: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]]
+
+
+def _links(ptr: np.ndarray, pos: np.ndarray):
+    """The link positions of the list holders at ``pos`` in a level's CSR
+    (``ptr``), in list order, and each holder's link count."""
+    start = ptr[pos]
+    cnt = ptr[pos + 1] - start
+    ends = np.cumsum(cnt)
+    return (np.arange(ends[-1] if ends.size else 0)
+            + np.repeat(start - ends + cnt, cnt)), cnt
+
+
 class ReferenceNet:
     """Host-mode reference net over a fixed-length window database.
 
@@ -112,6 +155,9 @@ class ReferenceNet:
         self.nodes: Dict[int, Node] = {}
         self.root: Optional[int] = None
         self.top_level: int = 0
+        self._snapshot: Optional[PlanSnapshot] = None
+        self.snapshot_builds = 0   # PlanSnapshot builds (one per change)
+        self.plans = 0             # range-query plans started
 
     # -- radii ------------------------------------------------------------
 
@@ -133,6 +179,57 @@ class ReferenceNet:
             return node.sub_radius
         return self.eps(node.level + 1)
 
+    # -- array snapshot for range queries ------------------------------------
+
+    def plan_snapshot(self) -> PlanSnapshot:
+        """The cached :class:`PlanSnapshot` of the net, built on first use
+        after a change; every method that changes the structure, a radius
+        or the database drops it."""
+        if self._snapshot is None:
+            self._snapshot = self._build_snapshot()
+            self.snapshot_builds += 1
+        return self._snapshot
+
+    def _changed(self) -> None:
+        self._snapshot = None
+
+    def _build_snapshot(self) -> PlanSnapshot:
+        rows = len(self.data)
+        level = np.full(rows, OBJ, np.int64)
+        sub_radius = np.zeros(rows)
+        has_children = np.zeros(rows, bool)
+        holders: Dict[int, List[int]] = {lv: [] for lv in
+                                         range(self.top_level + 1)}
+        for x, node in self.nodes.items():
+            level[x] = node.level
+            sub_radius[x] = self._subtree_radius(node)
+            if node.children:
+                assert node.level >= 0, f"plain object {x} holds a list"
+                has_children[x] = True
+                holders[node.level].append(x)
+        levels = {}
+        for lv, ids in holders.items():
+            ids.sort()
+            ptr = [0]
+            child: List[int] = []
+            link_r: List[float] = []
+            for x in ids:
+                node = self.nodes[x]
+                for k, c in enumerate(node.children):
+                    if c in self.nodes:
+                        child.append(c)
+                        link_r.append(self._link_radius(node, k))
+                ptr.append(len(child))
+            levels[lv] = (np.asarray(ids, np.int64),
+                          np.asarray(ptr, np.int64),
+                          np.asarray(child, np.int64),
+                          np.asarray(link_r, np.float64))
+            # Algorithm 3 visits levels top-down: a list may only hold
+            # nodes of lower levels
+            assert (level[levels[lv][2]] < lv).all(), \
+                f"a level-{lv} list holds a node of its level or above"
+        return PlanSnapshot(rows, sub_radius, has_children, levels)
+
     # -- construction -------------------------------------------------------
 
     def build(self, order: Optional[Sequence[int]] = None) -> "ReferenceNet":
@@ -153,6 +250,7 @@ class ReferenceNet:
         layer's reshard-in path: a shard that gains windows extends and
         bulk-loads instead of rebuilding from scratch."""
         rows = np.asarray(rows)
+        self._changed()
         base = len(self.counter.data)
         self.counter.extend(rows)
         self.data = self.counter.data
@@ -163,6 +261,7 @@ class ReferenceNet:
         :meth:`insert_plan` — evaluation counts and the resulting structure
         are bit-identical to the historical pair-at-a-time descent."""
         if self.root is None:
+            self._changed()
             self.root = idx
             self.top_level = 0
             self.nodes[idx] = Node(idx, 0, [], [], [], [])
@@ -242,6 +341,7 @@ class ReferenceNet:
 
     def _apply_insert(self, out: InsertOutcome) -> None:
         """Commit a planned insert: grow the root, then attach."""
+        self._changed()
         while self.top_level < out.new_top:
             self.top_level += 1
             self.nodes[self.root].level = self.top_level
@@ -330,6 +430,7 @@ class ReferenceNet:
     def _attach(self, idx: int, level: int, owners: Dict[int, float],
                 attach_level: int) -> None:
         assert owners, "inclusive property would be violated"
+        self._changed()
         ranked = sorted(owners.items(), key=lambda kv: kv[1])
         if self.num_max is not None:
             ranked = ranked[: self.num_max]
@@ -348,6 +449,7 @@ class ReferenceNet:
         Iterative (explicit stack): multi-parent DAGs built from large n can
         be deep enough that the recursive form hits Python's recursion
         limit; the <=-check still cuts every already-covered branch."""
+        self._changed()
         stack = [(p, new_r)]
         while stack:
             x, r = stack.pop()
@@ -365,6 +467,16 @@ class ReferenceNet:
     # -- deletion (Alg. 2) --------------------------------------------------
 
     def delete(self, idx: int) -> None:
+        """Alg. 2: remove object ``idx``; a member of its list that appears
+        in no other list is re-homed.
+
+        A re-homed reference keeps its own list only where it lands at its
+        old level or above, so links still point to lower levels and the
+        Lemma-4 radius still covers the list.  Where it lands lower, its
+        list dissolves instead, and each member left in no list is
+        re-homed in turn (such members hang only below the re-homed
+        references, so none is re-homed twice)."""
+        self._changed()
         node = self.nodes.pop(idx)
         if idx == self.root:
             raise NotImplementedError("root deletion requires re-rooting")
@@ -373,30 +485,35 @@ class ReferenceNet:
             if pn is not None:
                 k = pn.children.index(idx)
                 del pn.children[k], pn.child_dist[k], pn.child_level[k]
-        # re-home orphaned members of X's list (Alg. 2: if a member still
-        # appears in another list we do nothing, else re-insert it)
+        orphans = self._unlink(idx, node.children)
+        while orphans:
+            c = orphans.pop(0)
+            cn = self.nodes.pop(c)
+            sub = [(g, cn.child_dist[k], cn.child_level[k])
+                   for k, g in enumerate(cn.children) if g in self.nodes]
+            self.insert(c)
+            new_cn = self.nodes[c]
+            if new_cn.level < cn.level:
+                orphans.extend(self._unlink(c, [g for g, _, _ in sub]))
+                continue
+            for g, gd, gl in sub:
+                new_cn.children.append(g)
+                new_cn.child_dist.append(gd)
+                new_cn.child_level.append(gl)
+                self._grow_radius(c, gd + self.nodes[g].sub_radius)
+
+    def _unlink(self, p: int, children: Sequence[int]) -> List[int]:
+        """Drop ``p`` from the parents of ``children``; returns those left
+        with no parent."""
         orphans = []
-        for k, c in enumerate(node.children):
+        for c in children:
             cn = self.nodes.get(c)
             if cn is None:
                 continue
-            cn.parents.remove(idx)
+            cn.parents = [x for x in cn.parents if x != p]
             if not cn.parents:
                 orphans.append(c)
-        for c in orphans:
-            cn = self.nodes.pop(c)
-            sub = [(g, cn.child_dist[k], cn.child_level[k])
-                   for k, g in enumerate(cn.children)]
-            self.insert(c)
-            new_cn = self.nodes[c]
-            for g, gd, gl in sub:
-                gn = self.nodes.get(g)
-                if gn is not None:
-                    new_cn.children.append(g)
-                    new_cn.child_dist.append(gd)
-                    new_cn.child_level.append(gl)
-                    gn.parents.append(c)
-                    self._grow_radius(c, gd + gn.sub_radius)
+        return orphans
 
     # -- range query (Alg. 3 as bound propagation) ---------------------------
 
@@ -415,115 +532,132 @@ class ReferenceNet:
         the exact-evaluation count — is identical to the classic host path;
         only *who* evaluates a frontier (sequential driver vs the batched
         engine merging many plans per round) changes.
+
+        The plan walks :meth:`plan_snapshot`'s levels from the top, one
+        level per step, with its state in arrays over the net's rows:
+        ``dist``/``known`` (each distance counted once), the Lemma-4
+        intervals ``lo``/``hi`` (object) and ``slo``/``shi`` (subtree),
+        ``decided``/``inside`` (object verdicts), ``reached`` (a parent
+        expanded onto the node, so it awaits its level or the final
+        verdict round), and ``settled``/``accept`` (a whole-subtree
+        verdict).  Settled and processed are separate flags: a parent
+        that expands its list is done with, but its children are not, so
+        only ``settled`` is passed down the edges.  It is passed lazily —
+        down each level's edges just before the level below is visited —
+        which marks every descendant of a settled node before that
+        descendant could be requested or expanded, without walking the
+        subtree when it is settled.  Within a level every expanding
+        parent's links fold into the children's intervals at once
+        (``np.maximum.at`` / ``np.minimum.at``); as the bounds are valid
+        intervals of a metric, no verdict depends on the order the
+        parents were visited in.
         """
+        self.plans += 1
         if self.root is None:
             return []
-        known: Dict[int, float] = {}   # exact distances (each counted once)
-        lo: Dict[int, float] = {}      # accumulated object lower bounds
-        hi: Dict[int, float] = {}      # accumulated object upper bounds
-        slo: Dict[int, float] = {}     # subtree lower bounds
-        shi: Dict[int, float] = {}     # subtree upper bounds
-        closed: Set[int] = set()       # whole-subtree verdict settled
-        decided: Set[int] = set()      # object verdict settled
-        results: List[int] = []
+        snap = self.plan_snapshot()
+        n = snap.rows
+        dist = np.zeros(n)
+        known = np.zeros(n, bool)
+        lo = np.zeros(n)                 # object lower bounds
+        hi = np.full(n, INF)             # object upper bounds
+        slo = np.zeros(n)                # subtree lower bounds
+        shi = np.full(n, INF)            # subtree upper bounds
+        decided = np.zeros(n, bool)      # object verdict settled
+        inside = np.zeros(n, bool)       # ... and its value
+        reached = np.zeros(n, bool)      # expanded onto by a parent
+        settled = np.zeros(n, bool)      # whole-subtree verdict settled
+        accept = np.zeros(n, bool)       # ... and its value
 
-        def request(idxs, kind):
-            # de-dup against known, then yield ONE frontier for the batch
-            new = sorted(set(i for i in idxs if i not in known))
-            if new:
-                ds = yield batch_engine.Frontier(np.asarray(new, np.int64),
-                                                 kind)
-                known.update(zip(new, map(float, ds)))
+        def request(idxs: np.ndarray, kind: str):
+            # callers pass sorted ids that are not yet known: ONE frontier
+            ds = yield batch_engine.Frontier(idxs, kind)
+            dist[idxs] = np.asarray(ds, np.float64)
+            known[idxs] = True
+            fresh = idxs[~decided[idxs]]
+            inside[fresh] = dist[fresh] <= eps
+            decided[fresh] = True
 
-        def settle_subtree(n: int, accept: bool) -> None:
-            stack = [n]
-            while stack:
-                x = stack.pop()
-                if x in closed:
-                    continue
-                closed.add(x)
-                if x not in decided:
-                    decided.add(x)
-                    if accept:
-                        results.append(x)
-                stack.extend(self.nodes[x].children)
-
-        def decide(x: int, inside: bool) -> None:
-            if x in decided:
+        def pass_down(level: int) -> None:
+            # settled flags of this level's list holders onto their members
+            ids, ptr, child, _ = snap.levels[level]
+            pos = np.flatnonzero(settled[ids])
+            if not pos.size:
                 return
-            decided.add(x)
-            if inside:
-                results.append(x)
+            e, cnt = _links(ptr, pos)
+            c = child[e]
+            new = ~settled[c]
+            settled[c[new]] = True
+            accept[c[new]] = np.repeat(accept[ids[pos]], cnt)[new]
 
-        yield from request([self.root], batch_engine.EXACT)
-        d_root = known[self.root]
-        decide(self.root, d_root <= eps)
-        alive: Set[int] = {self.root}
-        pending_leaf: Set[int] = set()     # objects awaiting final verdict
-
+        root = np.asarray([self.root], np.int64)
+        yield from request(root, batch_engine.EXACT)
+        reached[root] = True
         for level in range(self.top_level, -1, -1):
-            # evaluate deferred expandable children whose level is reached;
-            # exact values feed Lemma-4 bound propagation below
-            defer = [c for c in alive
-                     if c not in known and c not in closed
-                     and self.nodes[c].level == level]
-            yield from request(defer, batch_engine.EXACT)
-            for c in defer:
-                d = known[c]
-                decide(c, d <= eps)
-
-            for n in sorted(c for c in alive
-                            if self.nodes[c].level == level):
-                alive.discard(n)
-                if n in closed:
-                    continue
-                node = self.nodes[n]
-                d = known[n]
-                sr = self._subtree_radius(node)
-                if d + sr <= eps:
-                    settle_subtree(n, accept=True)
-                    continue
-                if d - sr > eps:
-                    # n itself was decided exactly; only descendants settle
-                    for c in node.children:
-                        settle_subtree(c, accept=False)
-                    closed.add(n)
-                    continue
-                for k, c in enumerate(node.children):
-                    if c in closed:
-                        continue
-                    cn = self.nodes.get(c)
-                    if cn is None:
-                        continue
-                    r = self._link_radius(node, k)
-                    src = self._subtree_radius(cn)
-                    lo[c] = max(lo.get(c, 0.0), d - r)
-                    hi[c] = min(hi.get(c, INF), d + r)
-                    slo[c] = max(slo.get(c, 0.0), d - r - src)
-                    shi[c] = min(shi.get(c, INF), d + r + src)
-                    if shi[c] <= eps:
-                        settle_subtree(c, accept=True)
-                        continue
-                    if slo[c] > eps:
-                        settle_subtree(c, accept=False)
-                        continue
-                    if hi[c] <= eps:
-                        decide(c, True)
-                    elif lo[c] > eps:
-                        decide(c, False)
-                    if cn.children:
-                        alive.add(c)       # expandable: deferred to its level
-                    elif c not in decided:
-                        pending_leaf.add(c)
-                closed.add(n)
+            if level < self.top_level:
+                pass_down(level + 1)
+            ids, ptr, child, link_r = snap.levels[level]
+            pos = np.flatnonzero(reached[ids] & ~settled[ids])
+            if not pos.size:
+                continue
+            # evaluate the expandable nodes whose level is reached; exact
+            # values feed Lemma-4 bound propagation below
+            par = ids[pos]
+            defer = par[~known[par]]
+            if defer.size:
+                yield from request(defer, batch_engine.EXACT)
+            d = dist[par]
+            sr = snap.sub_radius[par]
+            whole_in = d + sr <= eps
+            whole_out = ~whole_in & (d - sr > eps)
+            # a parent's own verdict is exact already; whole_in settles its
+            # subtree through it, whole_out settles each member's subtree
+            settled[par[whole_in]] = True
+            accept[par[whole_in]] = True
+            if whole_out.any():
+                c = child[_links(ptr, pos[whole_out])[0]]
+                settled[c] = True
+            expand = ~(whole_in | whole_out)
+            if not expand.any():
+                continue
+            e, cnt = _links(ptr, pos[expand])
+            c = child[e]
+            keep = ~settled[c]
+            c = c[keep]
+            if not c.size:
+                continue
+            dp = np.repeat(d[expand], cnt)[keep]
+            r = link_r[e[keep]]
+            lo_e = dp - r
+            hi_e = dp + r
+            src = snap.sub_radius[c]
+            np.maximum.at(lo, c, lo_e)
+            np.minimum.at(hi, c, hi_e)
+            np.maximum.at(slo, c, lo_e - src)
+            np.minimum.at(shi, c, hi_e + src)
+            # a child listed by several parents repeats in c: every step
+            # below is idempotent, so it is classified once per listing
+            s_in = shi[c] <= eps
+            s_out = ~s_in & (slo[c] > eps)
+            settled[c[s_in | s_out]] = True
+            accept[c[s_in]] = True
+            c = c[~(s_in | s_out)]
+            reached[c] = True            # deferred to its level, or a leaf
+            c = c[~decided[c]]
+            v_in = hi[c] <= eps
+            v_out = ~v_in & (lo[c] > eps)
+            inside[c[v_in]] = True
+            decided[c[v_in | v_out]] = True
+        pass_down(0)
 
         # final object verdicts for leaves no parent managed to decide free;
         # only the <= eps verdict is consumed, so the LB cascade may prune
-        rem = [c for c in pending_leaf if c not in decided and c not in closed]
-        yield from request(rem, batch_engine.VERDICT)
-        for c in rem:
-            decide(c, known[c] <= eps)
-        return sorted(results)
+        rem = np.flatnonzero(reached & ~snap.has_children & ~decided
+                             & ~settled & ~known)
+        if rem.size:
+            yield from request(rem, batch_engine.VERDICT)
+        hit = np.where(decided, inside, settled & accept)
+        return np.flatnonzero(hit).tolist()
 
     def _subtree(self, n: int, include_self: bool = True) -> List[int]:
         out = [n] if include_self else []

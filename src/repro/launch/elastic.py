@@ -191,6 +191,7 @@ class ElasticIndex:
                            eps_prime=self.eps_prime,
                            tight_bounds=self.tight, counter=counter)
         net.build_batched(max_cohort=self.max_cohort)
+        net.plan_snapshot()     # the query plans' arrays, built once here
         return _Shard(net=net, flat=flatten_net(net), gids=ids)
 
     def _retire(self, shard: _Shard) -> None:
@@ -238,6 +239,9 @@ class ElasticIndex:
             if shard is None and new_ids:
                 shard = self._build_shard(new_ids)  # new/root-loss/compaction
             new_shards[w] = shard
+        for s in new_shards.values():
+            if s is not None:
+                s.net.plan_snapshot()   # rebuilt once where a shard changed
         carried = {id(s) for s in new_shards.values() if s is not None}
         for s in old_shards.values():
             if s is not None and id(s) not in carried:

@@ -84,6 +84,12 @@ class ServeConfig:
                 f"got {self.admission!r}")
 
 
+def _snapshot_builds(fleet) -> int:
+    """Plan snapshots built by the fleet's shard nets, summed."""
+    return sum(s.net.snapshot_builds for s in fleet.shards.values()
+               if s is not None)
+
+
 class ServeEngine:
     """Continuous-batching front end over an ElasticIndex fleet."""
 
@@ -148,8 +154,9 @@ class ServeEngine:
         return hook
 
     def _admit_one(self, req: Request, now: float) -> Optional[int]:
-        with spans.span(spans.SERVE_ADMIT, rid=req.rid):
+        with spans.span(spans.SERVE_ADMIT, rid=req.rid) as sp:
             fleet = self.fleet
+            builds = _snapshot_builds(fleet)
             q = np.asarray(req.query)
             qpad, q_lens = q[None], np.asarray([len(q)], np.int64)
             hook = self._lb_hook(fleet)
@@ -167,6 +174,9 @@ class ServeEngine:
             req.t_admit = now
             bid = self._engine.admit(groups, req.eps)
             self._inflight[bid] = (req, gids)
+            # plan snapshots this admission built (0 once every shard's
+            # is cached)
+            sp.set_metadata(snapshot_builds=_snapshot_builds(fleet) - builds)
         if self._engine.is_finished(bid):  # e.g. an empty fleet
             self._finalize(bid, now)
             return None
@@ -346,14 +356,16 @@ class ServeEngine:
     # -- accounting ---------------------------------------------------------
 
     def engine_stats(self) -> Dict[str, int]:
-        """Shared-cadence totals (merged rounds, eval split, swaps)."""
+        """Shared-cadence totals (merged rounds, eval split, swaps) and the
+        plan snapshots the fleet's shard nets have built."""
         e = self._engine
         return {"rounds": e.rounds, "exact_evals": e.exact_evals,
                 "verdict_evals": e.verdict_evals,
                 "fused_pruned": e.fused_pruned,
                 "lb_rows": e.lb_rows, "lb_pruned": e.lb_pruned,
                 "submitted": self.queue.submitted,
-                "completed": len(self.completed), "swaps": self.swaps}
+                "completed": len(self.completed), "swaps": self.swaps,
+                "plan_snapshot_builds": _snapshot_builds(self.fleet)}
 
     def latency_stats(self) -> Dict[str, float]:
         """Per-request latency percentiles over the completed set (clock

@@ -22,7 +22,8 @@ import jax
 
 #: ``ServeEngine.tick``, the whole beat (``admitted``, ``inflight``)
 SERVE_TICK = "serve.tick"
-#: one request's admission: plan priming, shard groups, LB hook (``rid``)
+#: one request's admission: plan priming, shard groups, LB hook (``rid``,
+#: ``snapshot_builds``: the plan snapshots it built, 0 once cached)
 SERVE_ADMIT = "serve.admit"
 #: one finished request: gid mapping and its hit set (``rid``, ``hits``)
 SERVE_FINALIZE = "serve.finalize"

@@ -134,6 +134,36 @@ def test_deletion_preserves_structure():
     assert net.range_query(q, 2.0) == want
 
 
+@pytest.mark.parametrize("dist_name,gen,eps_prime", CASES)
+@pytest.mark.parametrize("num_max", [None, 1])
+def test_many_deletions_keep_links_downward(dist_name, gen, eps_prime,
+                                            num_max):
+    """Deleting a third of the net re-homes references that may land
+    below their old level: their lists then dissolve and re-home too, so
+    every list still holds lower levels only, the invariants hold, and
+    range queries equal a scan of what is left."""
+    rng = np.random.default_rng(8)
+    data = gen(90, rng=rng)
+    dist = get(dist_name)
+    net = ReferenceNet(dist, data, eps_prime=eps_prime, num_max=num_max,
+                       tight_bounds=True).build_batched()
+    drop = [int(x) for x in rng.choice(90, 30, replace=False)
+            if x != net.root]
+    for x in sorted(drop, key=lambda x: net.nodes[x].level):
+        net.delete(x)
+    net.check_invariants()
+    for n in net.nodes.values():
+        assert all(net.nodes[c].level < n.level for c in n.children)
+        assert len(set(n.parents)) == len(n.parents)
+    keep = np.array(sorted(net.nodes))
+    naive = CountedDistance(dist, data)
+    for qi in (1, 44, 80):
+        d = naive.eval(data[qi], keep)
+        for eps in (float(np.quantile(d, 0.1)), float(np.median(d))):
+            assert net.range_query(data[qi], eps) == \
+                keep[d <= eps].tolist()
+
+
 def test_pruning_beats_mv_at_equal_space():
     """Paper §8.2 headline: RN prunes better than MV with comparable space."""
     data = _motif_strings(400)
