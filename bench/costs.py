@@ -1,19 +1,13 @@
-"""Operations and bytes that one wavefront DP row needs, and the peaks.
+"""Operations and bytes of the wavefront's DP rows, and the peaks.
 
 A row aligns a query of ``lx`` elements with a window of ``ly``, each
-element ``d`` numbers wide, over ``lx * ly`` DP cells.  Per cell:
-
-* Levenshtein: one comparison for the substitution cost, then three adds
-  and two minimums: 6 operations.
-* ERP: the L2 cost of the pair (``d`` subtractions, ``d`` multiplies,
-  ``d - 1`` adds, one square root: ``3d``), three adds and two minimums;
-  plus the gap cost of every element once (``d`` multiplies, ``d - 1``
-  adds, one square root: ``2d``).
-
-Bytes are what the answer cannot do without: both inputs read once as
-4-byte numbers, a 4-byte distance and a 4-byte verdict written.  Padding
-rows and the kernel's own layout copies are not counted, so the share of
-the roofline is the useful share.
+element ``d`` numbers wide, over ``lx * ly`` DP cells.  What one row
+needs is counted in ``bench/distances/<name>.py`` (``row_ops``,
+``row_bytes``): operations per cell as the distance's DP does them, and
+bytes the answer cannot do without, both inputs read once as 4-byte
+numbers and a 4-byte distance and verdict written.  Padding rows and the
+kernel's own layout copies are not counted, so the share of the roofline
+is the useful share.
 """
 
 from __future__ import annotations
@@ -21,19 +15,17 @@ from __future__ import annotations
 import json
 import pathlib
 
+from bench import byname
+
 PEAKS = pathlib.Path(__file__).with_name("peaks.json")
 
 
 def row_ops(distance: str, lx: int, ly: int, d: int) -> int:
-    if distance == "levenshtein":
-        return 6 * lx * ly
-    if distance == "erp":
-        return (3 * d + 5) * lx * ly + 2 * d * (lx + ly)
-    raise KeyError(f"no operation count for {distance!r}")
+    return byname.module("distances", distance).row_ops(lx, ly, d)
 
 
 def row_bytes(distance: str, lx: int, ly: int, d: int) -> int:
-    return 4 * d * (lx + ly) + 8
+    return byname.module("distances", distance).row_bytes(lx, ly, d)
 
 
 def peaks(device_kind: str) -> dict:
@@ -45,12 +37,20 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def roofline(distance: str, rows: int, lx: int, ly: int, d: int,
-             kernel_s: float, device_kind: str):
+def share(ops: int, nbytes: int, kernel_s: float, device_kind: str):
     """``(share in %, binding bound)``: the least time the chip could take
-    for ``rows`` real rows, over the kernel's measured device time."""
+    for ``ops`` operations over ``nbytes`` bytes, over the kernel's
+    measured device time."""
     p = peaks(device_kind)
-    t_ops = rows * row_ops(distance, lx, ly, d) / p["flops_per_s"]
-    t_bytes = rows * row_bytes(distance, lx, ly, d) / p["hbm_bytes_per_s"]
+    t_ops = ops / p["flops_per_s"]
+    t_bytes = nbytes / p["hbm_bytes_per_s"]
     bound = "compute" if t_ops >= t_bytes else "memory"
     return 100.0 * max(t_ops, t_bytes) / kernel_s, bound
+
+
+def roofline(distance: str, rows: int, lx: int, ly: int, d: int,
+             kernel_s: float, device_kind: str):
+    """:func:`share` of ``rows`` real rows of one shape."""
+    return share(rows * row_ops(distance, lx, ly, d),
+                 rows * row_bytes(distance, lx, ly, d), kernel_s,
+                 device_kind)

@@ -4,17 +4,22 @@ Everything a cell is made of is found by name:
 
 * the cell's entry in ``BENCHMARK.json`` names its configuration and its
   traffic mix, and the metrics it reports;
-* ``bench/configs/<config>.json``: the data generator and its sizes, the
+* ``bench/configs/<config>.json``: the request kind (``"kind"``,
+  ``window_range`` where absent), the data and its sizes, the
   ``RetrievalConfig`` the program is built with, and what ``correct``
   compares (``check``: each number's limit; ``control``: the control's
   kind);
+* ``bench/kinds/<kind>.py``: everything that depends on what a request
+  is: data and pool, warm-up, submitting and reading answers, the
+  reference, the control and the comparison (``window_range.py`` lists
+  what a kind provides);
 * ``bench/traffic/<cell>.json``: the mix, read by :mod:`bench.load`;
 * ``bench/metrics/<metric>.py`` (or ``<metric up to its first dot>.py``):
   ``read(run)`` returns the metric's value, or ``None`` where it finds
   nothing to read.
 
 The program is driven through its public path only: ``Retriever.build``,
-``Retriever.serve`` and the engine's ``submit``/``tick``, from this one
+the engine the kind opens on the fleet and its ``tick``, from this one
 thread, with every request stamped with the time it was due.
 """
 
@@ -23,7 +28,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -34,7 +38,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import devtrace, gen, load, reference
+from bench import byname, devtrace, load
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -84,6 +88,12 @@ class Cell:
     def distance(self) -> str:
         return self.config["retrieval"]["distance"]
 
+    @property
+    def kind(self):
+        """The module of the configuration's request kind."""
+        return byname.module("kinds", self.config.get("kind",
+                                                      "window_range"))
+
 
 def load_cell(name: str, spec_path: pathlib.Path = ROOT / "BENCHMARK.json"
               ) -> Cell:
@@ -105,13 +115,10 @@ def reader(metric: str):
     """``read`` of ``bench/metrics/<metric>.py``, else of the file named by
     the metric's name up to its first dot."""
     for stem in (metric, metric.split(".")[0]):
-        path = BENCH / "metrics" / f"{stem}.py"
-        if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+        try:
+            return byname.module("metrics", stem).read
+        except FileNotFoundError:
+            continue
     raise FileNotFoundError(f"no reader for metric {metric!r}")
 
 
@@ -119,19 +126,18 @@ def reader(metric: str):
 
 @dataclasses.dataclass
 class Served:
-    """What set-up hands the window: data, query pool, the built fleet."""
+    """What set-up hands the window: data, query pool, the built fleet,
+    and the request kind that submits to it."""
     data: np.ndarray
     pool: np.ndarray
     fleet: object
     eps: float
+    kind: object
 
 
 def make_data(cell: Cell, seed: int):
-    d = dict(cell.config["data"])
-    data = gen.GENERATORS[d.pop("generator")](seed=seed, **d)
-    pool = gen.perturb(data, int(cell.traffic["pool"]), seed,
-                       **cell.traffic.get("queries", {}))
-    return data, pool
+    """The database and the query pool, from the seed."""
+    return cell.kind.make(cell, seed)
 
 
 def build(cell: Cell, seed: int) -> Served:
@@ -143,29 +149,9 @@ def build(cell: Cell, seed: int) -> Served:
     rc = RetrievalConfig.from_dict(cell.config["retrieval"])
     fleet = Retriever.build(rc, data)
     eps = float(cell.traffic["eps"])
-    warm(cell.distance, data, pool, eps, int(cell.traffic["warm_rows"]))
+    cell.kind.warm(cell, data, pool, eps)
     gc.collect()   # set-up's garbage goes in set-up, not in the window
-    return Served(data, pool, fleet, eps)
-
-
-def warm(distance: str, data, pool, eps: float, top: int) -> int:
-    """Run one packed dispatch of each batch class a round can reach.
-
-    The kernel registry compiles one program per power-of-two batch from
-    8 rows up; the traffic file's ``warm_rows`` is the largest round its
-    cell is warmed for.  (The served path screens rows with the envelope
-    tier on the host, so no envelope kernel runs.)  Returns the classes
-    warmed."""
-    from repro.kernels import dispatch
-    rows, n = 8, 0
-    while True:
-        idx = np.arange(min(rows, top))
-        dispatch.packed_batch(distance, pool[idx % len(pool)],
-                              data[idx % len(data)], eps=eps)
-        n += 1
-        if rows >= top:
-            return n
-        rows *= 2
+    return Served(data, pool, fleet, eps, cell.kind)
 
 
 # -- the window ----------------------------------------------------------------
@@ -257,7 +243,8 @@ def serve_window(served: Served, traffic: dict, seconds: float, seed: int,
     each answered one replaced at once.  After the close, requests still
     open are served for at most ``drain_s`` more; ``on_close`` runs at the
     close (the tracer stops there)."""
-    engine = served.fleet.serve(served.eps)
+    kind = served.kind
+    engine = kind.serve(served.fleet, served.eps)
     pool, stream = served.pool, load.query_stream(traffic, seed)
     closed = traffic["loop"] == "closed"
     due = (np.zeros(int(traffic["outstanding"])) if closed
@@ -274,7 +261,7 @@ def serve_window(served: Served, traffic: dict, seconds: float, seed: int,
     c0 = _counters()
 
     def submit(q: int, at: float) -> None:
-        req = engine.submit(pool[q], now=at)
+        req = kind.submit(engine, pool[q], at)
         s = Sent(q, at, clock(), req)
         sent.append(s)
         by_rid[req.rid] = s
@@ -350,7 +337,7 @@ def serve_window(served: Served, traffic: dict, seconds: float, seed: int,
 
 # -- the check -----------------------------------------------------------------
 
-def _numbers(window: Window, hits_of, dists, eps: float) -> dict:
+def _numbers(window: Window, answer_of, refs, gaps, eps: float) -> dict:
     """Unanswered requests, wrong verdicts, and the widest gap by which a
     wrong verdict's reference distance lies from ``eps``."""
     n_unanswered = n_wrong = 0
@@ -361,7 +348,7 @@ def _numbers(window: Window, hits_of, dists, eps: float) -> dict:
             n_unanswered += 1
             per_request.append(math.inf)
             continue
-        g = reference.verdict_gaps(hits_of(s), dists[s.query], eps)
+        g = gaps(answer_of(s), refs[s.query], eps)
         n_wrong += len(g)
         worst = float(g.max()) if len(g) else -1.0
         gap = max(gap, worst)
@@ -381,30 +368,22 @@ def _failed(per_request: List[float], limits: Dict[str, float]) -> int:
 
 def check(cell: Cell, served: Served, window: Window, *,
           control: bool = False, threads: int = THREADS) -> dict:
-    """The served hit sets (or, with ``control``, the control's) against
-    the plain reference.  Returns the compared numbers with their limits,
-    the failed requests, and the other numbers read."""
+    """The served answers (or, with ``control``, the control's) against
+    the plain reference of the cell's request kind.  Returns the compared
+    numbers with their limits, the failed requests, and the other numbers
+    read."""
+    kind = cell.kind
     queries = sorted({s.query for s in window.sent})
-    ref = reference.distances(cell.distance, served.pool[queries],
-                              served.data, threads=threads)
-    dists = dict(zip(queries, ref))
+    ref = kind.reference(cell, served.data, served.pool[queries], threads)
+    refs = dict(zip(queries, ref))
     eps = served.eps
     if not control:
-        hits_of = lambda s: s.req.hits  # noqa: E731
+        answer_of = lambda s: kind.answer(s.req)  # noqa: E731
     else:
-        kind = cell.config["control"]
-        if kind == "open_ball":
-            hits = {q: np.flatnonzero(d < eps) for q, d in dists.items()}
-        elif kind == "bf16":
-            cref = reference.distances(cell.distance, served.pool[queries],
-                                       served.data, control=True,
-                                       threads=threads)
-            hits = {q: np.flatnonzero(d <= eps)
-                    for q, d in zip(queries, cref)}
-        else:
-            raise KeyError(f"unknown control {kind!r}")
-        hits_of = lambda s: hits[s.query]  # noqa: E731
-    nums = _numbers(window, hits_of, dists, eps)
+        answers = dict(zip(queries, kind.control(
+            cell, served.data, served.pool[queries], ref, eps, threads)))
+        answer_of = lambda s: answers[s.query]  # noqa: E731
+    nums = _numbers(window, answer_of, refs, kind.gaps, eps)
     limits = cell.config["check"]
     compared = {k: {"value": nums[k], "limit": v} for k, v in limits.items()}
     return {"compared": compared,
@@ -492,9 +471,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                         "p95": percentile_ms(late, 95),
                         "max": percentile_ms(late, 100),
                         "max_at_s": worst and worst.due - window.t0},
-        "hits_per_request": (float(np.mean([len(s.req.hits) for s in
-                                            window.answered()]))
-                             if lat else None),
+        "hits_per_request": (float(np.mean([
+            cell.kind.size(cell.kind.answer(s.req))
+            for s in window.answered()])) if lat else None),
         "ticks": len(window.ticks), "counters": window.counters,
         "engine": window.engine, "notes": run_.notes,
         "check_read": verdict["read"]}})

@@ -1,20 +1,20 @@
-"""Wavefront kernel: the least time the chip could take for the real rows
-dispatched in the window (``bench.costs``), over the summed device time of
-the kernel's events in the trace.  Notes which bound binds."""
+"""Wavefront kernel: the least time the chip could take for the real work
+dispatched in the window, over the summed device time of the kernel's
+events in the trace.  The work (operations and bytes) is counted by the
+cell's request kind (``kernel_work``), which knows what its rows are.
+Notes which bound binds."""
 
 from bench import costs
 
 
 def read(run):
-    t = run.trace
-    kernel_s = (t or {}).get("kernel_s", {}).get("wavefront", 0.0)
-    rows = run.window.counters["rows"]
-    if not kernel_s or not rows:
+    kernel_s = (run.trace or {}).get("kernel_s", {}).get("wavefront", 0.0)
+    work = run.cell.kind.kernel_work(run) if kernel_s else None
+    if not work or not work[0]:
         return None
-    data = run.cell.config["data"]
-    d = 2 if data["generator"] == "trajectories" else 1
-    share, bound = costs.roofline(run.cell.distance, rows, data["l"],
-                                  data["l"], d, kernel_s, run.device_kind)
-    run.notes["wavefront_roofline"] = {"bound": bound, "rows": rows,
-                                       "kernel_s": kernel_s}
+    ops, nbytes = work
+    share, bound = costs.share(ops, nbytes, kernel_s, run.device_kind)
+    run.notes["wavefront_roofline"] = {
+        "bound": bound, "rows": run.window.counters["rows"], "ops": ops,
+        "bytes": nbytes, "kernel_s": kernel_s}
     return share

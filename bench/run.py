@@ -4,13 +4,14 @@
       --seconds 30 --trace 0
 
 Builds the cell's data and fleet from ``--seed``, warms every kernel shape
-the window can reach, serves the cell's traffic through
-``Retriever.serve`` for ``--seconds``, checks every answer against the
-plain reference, and prints one JSON object as the last line of standard
-output: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
-end-to-end metrics; with ``--trace 1`` its per-layer metrics, read from a
-profiler trace of the window), ``device`` and ``check`` (each compared
-number with its limit, also the last lines of standard error).  Exits
+the window can reach, serves the cell's traffic through the engine its
+request kind opens (``bench/kinds/``) for ``--seconds``, checks every
+answer against the kind's plain reference, and prints one JSON object as
+the last line of standard output: ``correct``, ``attempted``,
+``failed``, ``metrics`` (the cell's end-to-end metrics; with ``--trace
+1`` its per-layer metrics, read from a profiler trace of the window),
+``device`` and ``check`` (each compared number with its limit, also the
+last lines of standard error).  Exits
 non-zero, printing no result, when JAX finds no TPU or fewer chips than
 the cell asks for, and when anything was traced or compiled inside the
 measured window.

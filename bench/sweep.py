@@ -7,10 +7,12 @@ One process builds the cell's fleet once, then serves the cell's traffic
 at each rate in turn for ``--seconds`` (each step on a seed of its own),
 and prints one JSON line per step: the offered rate, the rate served
 inside the step, the backlog (requests sent but not answered) at the
-step's close, and p50/p95 of due time to answer.  It stops after the
-first step whose backlog grows: more than half a second of arrivals left
-open at the close.  The knee is the highest rate before that; the cell's
-traffic file freezes a share of it (PERF.md says which, and why).
+step's close, p50/p95 of due time to answer, and the rows of the
+largest round (a traffic file's ``warm_rows`` covers it).  It stops
+after the first step whose backlog grows: more than half a second of
+arrivals left open at the close.  The knee is the highest rate before
+that; the cell's traffic file freezes a share of it (PERF.md says
+which, and why).
 """
 
 import argparse
@@ -59,6 +61,8 @@ def main(argv=None) -> int:
                       len(w.sent) - len(lat),
                       "p50_ms": harness.percentile_ms(lat, 50),
                       "p95_ms": harness.percentile_ms(lat, 95),
+                      "largest_round_rows":
+                      w.counters["largest_round_rows"],
                       "round_ms": 1e3 * sum(b - a for a, b in w.ticks)
                       / max(1, len(w.ticks))})
         if backlog > max(4, 0.5 * rate):

@@ -92,6 +92,56 @@ def test_arrivals_same_work_for_every_seed():
                                rtol=0.2, atol=1e-3)
 
 
+def test_arrivals_and_popularity_found_by_name():
+    """``poisson`` and ``uniform`` are what a mix without the keys gets."""
+    mix = {"rate_rps": 11.2, "schedule_seed": 1, "pool": 32}
+    assert np.array_equal(load.arrivals(mix, 50.0), load.arrivals(
+        dict(mix, arrivals="poisson"), 50.0))
+    a, b = (load.query_stream(m, 9) for m in (mix, dict(
+        mix, popularity="uniform")))
+    assert [next(a) for _ in range(100)] == [next(b) for _ in range(100)]
+    with pytest.raises(FileNotFoundError):
+        load.arrivals(dict(mix, arrivals="no_such_pattern"), 1.0)
+
+
+def test_mmpp_bursts_keep_the_mean_rate():
+    """4 x the mean for 1 s of every 10 s; the rate between bursts is
+    what keeps the mean, and the schedule is fixed by its seed."""
+    mix = {"arrivals": "mmpp", "rate_rps": 20.0, "schedule_seed": 1,
+           "mmpp": {"peak_x": 4.0, "burst_s": 1.0, "period_s": 10.0}}
+    a = load.arrivals(mix, 200.0)
+    assert len(a) == 4000 and np.all(np.diff(a) >= 0)
+    assert 0 <= a.min() and a.max() < 200.0
+    assert np.array_equal(a, load.arrivals(dict(mix), 200.0))
+    assert not np.array_equal(a, load.arrivals(
+        dict(mix, schedule_seed=BIG_SEED), 200.0))
+    burst = np.mod(a, 10.0) < 1.0
+    low = 20.0 * (10.0 - 4.0) / 9.0
+    assert burst.sum() / 20.0 == pytest.approx(4 * 20.0, rel=0.05)
+    assert (~burst).sum() / 180.0 == pytest.approx(low, rel=0.05)
+    with pytest.raises(ValueError):
+        load.arrivals(dict(mix, mmpp={"peak_x": 20.0, "burst_s": 1.0,
+                                      "period_s": 10.0}), 10.0)
+
+
+def test_zipf_popularity():
+    """Rank k is asked in proportion to k ** -s; the seed permutes which
+    pool query holds each rank."""
+    mix = {"popularity": "zipf", "zipf": {"s": 1.1}, "pool": 64}
+
+    def counts(seed):
+        s = load.query_stream(mix, seed)
+        return np.bincount([next(s) for _ in range(40000)], minlength=64)
+    c = counts(BIG_SEED)
+    assert np.array_equal(c, counts(BIG_SEED))
+    ranked = np.sort(c)[::-1] / c.sum()
+    p = np.arange(1, 65) ** -1.1
+    np.testing.assert_allclose(ranked[:8], (p / p.sum())[:8], rtol=0.05)
+    assert c.min() > 0
+    top = lambda c: np.argsort(c)[::-1][:5].tolist()  # noqa: E731
+    assert top(c) != top(counts(BIG_SEED + 1))
+
+
 def test_query_stream_passes():
     s = load.query_stream({"pool": 32}, 9)
     one = [next(s) for _ in range(32)]
@@ -106,6 +156,7 @@ def test_costs_and_peaks():
                                   "TPU v5 lite")
     assert bound == "memory"
     assert share == pytest.approx(100 * 10**6 * 168 / 819e9)
+    assert costs.row_bytes("erp", 20, 20, 2) == 4 * 2 * 40 + 8
     with pytest.raises(KeyError):
         costs.peaks("TPU v99")
 

@@ -20,9 +20,9 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import harness, reference  # noqa: E402
+from bench import byname, harness, reference  # noqa: E402
 
-CELLS = ("protein_lev.steady", "traj_erp.steady")
+CELLS = ("protein_lev.steady", "traj_erp.steady", "traj_erp.wide")
 SEED = 2**31 + 77
 
 
@@ -125,7 +125,8 @@ def _window(served, queries):
 
 
 @pytest.mark.parametrize("cell, windows, pool", [
-    ("protein_lev.steady", 2048, 64), ("traj_erp.steady", 4096, 256)])
+    ("protein_lev.steady", 2048, 64), ("traj_erp.steady", 4096, 256),
+    ("traj_erp.wide", 4096, 256)])
 def test_control_is_caught(cell, windows, pool):
     """The control in the program's place: ``correct`` comes out false.
     (Protein: the open ball ``d < eps``; ERP: the reference in
@@ -134,7 +135,7 @@ def test_control_is_caught(cell, windows, pool):
     c.config["data"]["n_windows"] = windows
     c.traffic["pool"] = pool
     data, qs = harness.make_data(c, 5)
-    served = harness.Served(data, qs, None, float(c.traffic["eps"]))
+    served = harness.Served(data, qs, None, float(c.traffic["eps"]), c.kind)
     w = _window(served, range(pool))
     out = harness.check(c, served, w, control=True, threads=4)
     assert not out["correct"] and out["failed"] > 0
@@ -164,8 +165,9 @@ def _alter_answer(real):
     return finish
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", ["half_batch", "altered_answer"])
-def test_planted_fault_is_caught(tiny, monkeypatch, fault):
+def test_planted_fault_is_caught(tiny, monkeypatch, fault, cell):
     from repro.kernels import dispatch
     from repro.serve import queue
     if fault == "half_batch":
@@ -174,7 +176,7 @@ def test_planted_fault_is_caught(tiny, monkeypatch, fault):
     else:
         monkeypatch.setattr(queue.Request, "finish",
                             _alter_answer(queue.Request.finish))
-    out = run("protein_lev.steady", seconds=3.0)
+    out = run(cell, seconds=3.0)
     assert not out["correct"] and out["failed"] > 0
 
 
@@ -219,7 +221,7 @@ def test_compile_inside_the_window_fails_the_run(tiny, monkeypatch):
     fleet's build compiles some classes itself, so the planted warm-up
     drops every compiled kernel.)"""
     from repro.kernels import registry
-    monkeypatch.setattr(harness, "warm",
+    monkeypatch.setattr(byname.module("kinds", "window_range"), "warm",
                         lambda *a, **k: registry.clear_cache())
     with pytest.raises(harness.CompiledInWindow, match="kernel_traces"):
         run("protein_lev.steady")
@@ -235,3 +237,29 @@ def test_served_path_runs_no_envelope_kernel(tiny, monkeypatch):
     monkeypatch.setattr(dispatch, "packed_envelope", refuse)
     out = run("traj_erp.steady")
     assert out["correct"] and out["attempted"] > 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("script, args", [
+    ("calibrate", ["--seeds", str(SEED), "--seconds", "1"]),
+    ("sweep", ["--seed", str(SEED), "--rates", "2", "4", "--seconds",
+               "1"]),
+    ("split", ["--seed", str(SEED), "--seconds", "1"])])
+def test_chip_scripts_drive_each_cell(tiny, monkeypatch, cell, script,
+                                     args):
+    """``bench/calibrate.py``, ``sweep.py`` and ``split.py`` through the
+    harness, with the chip steered to the CPU."""
+    import importlib
+    lines = []
+    monkeypatch.setattr(harness, "emit", lambda line: lines.append(
+        json.loads(json.dumps(line))))
+    main = importlib.import_module(f"bench.{script}").main
+    assert main(["--workload", cell] + args) == 0
+    if script == "calibrate":
+        assert lines[0]["correct"] and lines[0]["requests"] > 0
+        assert set(lines[0]["control"]) == set(lines[0]["program"])
+    elif script == "sweep":
+        assert [x["rate_rps"] for x in lines[1:]] == [2.0, 4.0]
+        assert all(x["largest_round_rows"] > 0 for x in lines[1:])
+    else:
+        assert lines[0]["workload"] == cell and lines[0]["ticks"] > 0
