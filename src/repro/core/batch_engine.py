@@ -80,13 +80,21 @@ Plan = Generator
 
 @dataclasses.dataclass
 class Frontier:
-    """One round of undecided candidates of a single frontier plan."""
+    """One round of undecided candidates of a single frontier plan.
+
+    ``rows`` gives each candidate's query row within its plan: 0 for a
+    plan of one query row (the default), the row for a plan that serves
+    several at once (``ReferenceNet.range_query_rows_plan``; read by
+    :class:`FleetBatchEngine` only, see :class:`ShardPlans`)."""
     idxs: np.ndarray
     kind: str = EXACT
     bucket: str = QUERY     # counter accounting bucket (QUERY / BUILD)
+    rows: Optional[np.ndarray] = None
 
     def __post_init__(self):
         self.idxs = np.asarray(self.idxs, np.int64)
+        self.rows = np.zeros(len(self.idxs), np.int64) \
+            if self.rows is None else np.asarray(self.rows, np.int64)
 
 
 def drive(plan: Plan, counter: CountedDistance, q: np.ndarray,
@@ -254,7 +262,11 @@ class ShardPlans:
     ``plans[i]`` is a range-query plan over this shard's *local* database
     (frontier idxs index ``data``); ``queries`` holds one padded query row
     per plan with ``q_lens`` giving the actual lengths (ragged batches share
-    one padded width across the whole fleet).  ``shard`` is the fleet
+    one padded width across the whole fleet).  Plan ``i`` reads its
+    query rows from ``queries[i:]``: a frontier's row ``r``
+    (``Frontier.rows``) is ``queries[i + r]``, so a plan of one row is
+    row ``i``, and a plan of many rows (the segments of a match request)
+    sits alone in its group.  ``shard`` is the fleet
     worker slot, the key of the envelope hook's per-shard envelopes.
     ``lb`` optionally overrides
     the engine-wide envelope hook for this group's rows — the serve layer
@@ -330,6 +342,10 @@ class FleetBatchEngine:
         self.fused_pruned = 0
         self.lb_rows = 0
         self.lb_pruned = 0
+        self.last_rows = 0      # rows of the latest merged round
+        #: the evaluated rows' real len_x * len_y, and len_x + len_y, summed
+        self.cells = 0
+        self.elems = 0
         self._next_bid = 0
         self._admitted: Dict[int, _Admitted] = {}
         self._state: Dict[tuple, Frontier] = {}   # (bid, g, i) -> frontier
@@ -391,6 +407,9 @@ class FleetBatchEngine:
             keeps, lbs = self._screen(parts)
             xs, ys, lx, ly, verdict, eps_rows = self._gather(parts, keeps)
             rnd.set_metadata(rows=len(xs))
+            self.last_rows = len(xs)
+            self.cells += int(np.dot(lx, ly))
+            self.elems += int(lx.sum() + ly.sum())
             if len(xs):
                 with spans.span(spans.FLEET_EVALUATE):
                     ds, n_pruned = self.evaluate(
@@ -418,9 +437,13 @@ class FleetBatchEngine:
                 keep, lbv = np.ones(m, bool), None
                 hook = grp.lb if grp.lb is not None else self.lb
                 if hook is not None and fr.kind == VERDICT and m:
-                    lbv = np.asarray(
-                        hook(grp.shard, fr.idxs, grp.queries[i],
-                             int(grp.q_lens[i])), np.float32)
+                    # the hook screens one query row at a time
+                    lbv = np.empty(m, np.float32)
+                    for r in np.unique(fr.rows):
+                        at = fr.rows == r
+                        lbv[at] = hook(grp.shard, fr.idxs[at],
+                                       grp.queries[i + r],
+                                       int(grp.q_lens[i + r]))
                     keep = lbv <= batch.eps
                     rows += m
                     pruned += int(m - keep.sum())
@@ -450,9 +473,10 @@ class FleetBatchEngine:
             for (batch, g, i, fr), keep in zip(parts, keeps):
                 grp = batch.groups[g]
                 mk = int(keep.sum())
-                xs.append(np.repeat(grp.queries[i][None], mk, 0))
+                r = i + fr.rows[keep]
+                xs.append(grp.queries[r])
+                lx.append(np.asarray(grp.q_lens, np.int64)[r])
                 ys.append(grp.data[fr.idxs[keep]])
-                lx.append(np.full(mk, int(grp.q_lens[i]), np.int64))
                 ly.append(np.full(mk, grp.data.shape[1], np.int64))
                 verdict.append(np.full(mk, fr.kind == VERDICT))
                 eps.append(np.full(

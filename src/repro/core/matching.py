@@ -55,6 +55,167 @@ class SegmentHit:
     distance: float
 
 
+@dataclasses.dataclass
+class StartPairs:
+    """Step 5's candidates grouped by start pair ``(seq, xs, qs)``.
+
+    Every candidate pair that starts at ``X[seq][xs]`` and ``Q[qs]`` is one
+    end cell of the same alignment DP, so a group is one verify row.
+    ``mask[g, i - lam, q]`` marks the candidate ``|SX| = i``,
+    ``|SQ| = i + q - lambda0`` of group ``g``; ``lx``/``ly`` are the
+    group's longest candidate lengths, the row's real lengths."""
+    seq: np.ndarray
+    xs: np.ndarray
+    qs: np.ndarray
+    mask: np.ndarray     # (G, Lx - lam + 1, 2 * lambda0 + 1) bool
+    lx: np.ndarray
+    ly: np.ndarray
+
+    def __len__(self) -> int:
+        return len(self.seq)
+
+
+def _rows(a: np.ndarray, starts: np.ndarray, lens: np.ndarray, W: int
+          ) -> np.ndarray:
+    """``a[s : s + n]`` for each start and length, zero-padded to ``W``."""
+    col = np.arange(W)
+    pad = np.concatenate([a, np.zeros((W,) + a.shape[1:], a.dtype)])
+    rows = pad[np.asarray(starts, np.int64)[:, None] + col[None, :]]
+    keep = (col[None, :] < np.asarray(lens)[:, None]).reshape(
+        (len(starts), W) + (1,) * (a.ndim - 1))
+    return np.where(keep, rows, 0).astype(a.dtype)
+
+
+class MatchScope:
+    """What subsequence matching keeps beside a window index: the
+    sequences, each window's (sequence, start), and steps 3 and 5 as
+    vectorised numpy over whole requests — the served path's filter rows
+    and its candidate envelope, with no per-candidate Python loop.
+
+    Verify rows are padded to one shape class: the longest candidate is
+    ``Lx = lam + l`` elements of ``X`` against ``Ly = Lx + lambda0`` of
+    ``Q`` (the §7 envelope reaches at most ``l`` before a window and
+    ``lam`` past its start)."""
+
+    def __init__(self, seqs: Sequence[np.ndarray], lam: int, lambda0: int):
+        self.lam, self.lambda0 = int(lam), int(lambda0)
+        self.l = seg.window_length(lam)
+        self.Lx = self.lam + self.l
+        self.Ly = self.Lx + self.lambda0
+        seqs = [np.asarray(x) for x in seqs]
+        self.windows, self.meta = seg.partition_windows(seqs, lam)
+        self.win_seq = np.array([w.seq_id for w in self.meta], np.int64)
+        self.win_start = np.array([w.start for w in self.meta], np.int64)
+        self.lengths = np.array([len(x) for x in seqs], np.int64)
+        self.offsets = np.concatenate([[0], np.cumsum(self.lengths)[:-1]])
+        self.flat = np.concatenate(seqs)
+
+    # -- step 3 -----------------------------------------------------------
+
+    def segments(self, Q: np.ndarray):
+        """Every query segment of length ``l - lambda0 .. l + lambda0`` as
+        one padded row: ``(rows, lengths, starts)``, by length and then
+        start (``seg.query_segments``' order).  No rows when ``Q`` is
+        shorter than ``lam``: no pair can match."""
+        Q = np.asarray(Q)
+        W = self.l + self.lambda0
+        lens = [ln for ln in range(max(1, self.l - self.lambda0), W + 1)
+                if ln <= len(Q)] if len(Q) >= self.lam else []
+        q_lens = np.concatenate([np.full(len(Q) - ln + 1, ln, np.int64)
+                                 for ln in lens] or [np.zeros(0, np.int64)])
+        starts = np.concatenate([np.arange(len(Q) - ln + 1)
+                                 for ln in lens] or [np.zeros(0, np.int64)])
+        return _rows(Q, starts, q_lens, W), q_lens, starts
+
+    # -- step 5 -----------------------------------------------------------
+
+    def candidates(self, q_len: int, seg_start: np.ndarray,
+                   seg_len: np.ndarray, win: np.ndarray) -> StartPairs:
+        """The §7 envelope of each (segment, window) hit, grouped by start
+        pair: for a segment ``Q[a, b)`` on window ``X[c, c + l)``,
+        ``qs in [a - l - lambda0, a]``, ``qe in [b, b + l + lambda0]``,
+        ``xs in [c - l, c]``, ``xe in [c + l, c + lam]``, inside both
+        sequences, with ``|SX|, |SQ| >= lam`` and
+        ``||SX| - |SQ|| <= lambda0`` — ``SubsequenceMatcher``'s candidate
+        set, as a union of one rectangle of end cells per (hit, start)."""
+        lam, l0, l = self.lam, self.lambda0, self.l
+        a = np.asarray(seg_start, np.int64)
+        ln = np.asarray(seg_len, np.int64)
+        win = np.asarray(win, np.int64)
+        sq = self.win_seq[win]
+        c = self.win_start[win]
+        nX = self.lengths[sq]
+        dx = np.arange(l + 1)[None, :, None]
+        dq = np.arange(l + l0 + 1)[None, None, :]
+        H = (len(a), 1, 1)
+        a3, ln3, c3 = a.reshape(H), ln.reshape(H), c.reshape(H)
+        # end-cell rectangle of each (hit, xs = c - dx, qs = a - dq)
+        i_lo = np.maximum(l + dx, lam)
+        i_hi = np.minimum(nX.reshape(H) - c3, lam) + dx
+        j_lo = np.maximum(ln3 + dq, lam)
+        j_hi = np.minimum(q_len - a3, ln3 + l + l0) + dq
+        ok = ((c3 - dx >= 0) & (a3 - dq >= 0) & (i_lo <= i_hi)
+              & (j_lo <= j_hi) & (j_lo <= i_hi + l0) & (i_lo - l0 <= j_hi))
+        h, x, q = np.nonzero(ok)
+        shape = ok.shape
+        pick = lambda v: np.broadcast_to(v, shape)[h, x, q]  # noqa: E731
+        iv, jv = self._ends()
+        cell = ((iv >= pick(i_lo)[:, None, None])
+                & (iv <= pick(i_hi)[:, None, None])
+                & (jv >= pick(j_lo)[:, None, None])
+                & (jv <= pick(j_hi)[:, None, None]))
+        xs = c[h] - x
+        qs = a[h] - q
+        key = (sq[h] * (int(self.lengths.max()) + 1) + xs) * (q_len + 1) \
+            + qs
+        uniq, first, inv = np.unique(key, return_index=True,
+                                     return_inverse=True)
+        order = np.argsort(inv, kind="stable")
+        cuts = np.flatnonzero(np.diff(inv[order])) + 1
+        mask = np.logical_or.reduceat(cell[order], np.r_[0, cuts], axis=0) \
+            if len(order) else np.zeros((0,) + cell.shape[1:], bool)
+        i_any = mask.any(axis=2)
+        lx = lam + (mask.shape[1] - 1 - np.argmax(i_any[:, ::-1], axis=1))
+        ly = np.where(mask, jv, 0).max(axis=(1, 2)) if len(mask) \
+            else np.zeros(0, np.int64)
+        return StartPairs(sq[h][first], xs[first], qs[first], mask,
+                          lx.astype(np.int64), ly.astype(np.int64))
+
+    def verify_rows(self, Q: np.ndarray, pairs: StartPairs):
+        """One row per start pair, padded to ``(Lx, Ly)``:
+        ``X[seq][xs : xs + lx]`` against ``Q[qs : qs + ly]``."""
+        return (_rows(self.flat, self.offsets[pairs.seq] + pairs.xs,
+                      pairs.lx, self.Lx),
+                _rows(np.asarray(Q), pairs.qs, pairs.ly, self.Ly))
+
+    def _ends(self):
+        """Each band cell's ``(i, j)``, shaped ``(1, Lx-lam+1, nb)``."""
+        iv = (self.lam + np.arange(self.Lx - self.lam + 1))[None, :, None]
+        return iv, iv + np.arange(2 * self.lambda0 + 1)[None, None, :] \
+            - self.lambda0
+
+    def band_cells(self, lx: np.ndarray, ly: np.ndarray) -> int:
+        """End cells that verify rows of these real lengths emit."""
+        iv, jv = self._ends()
+        lx = np.asarray(lx)[:, None, None]
+        ly = np.asarray(ly)[:, None, None]
+        return int(((iv <= lx) & (jv >= self.lam) & (jv <= ly)).sum())
+
+    def collect(self, pairs: StartPairs, band: np.ndarray, eps: float
+                ) -> List[MatchPair]:
+        """The candidates whose verified distance is ``<= eps``, sorted by
+        ``(seq_id, x_start, x_len, q_start, q_len)``; ``band`` holds each
+        row's end cells as ``dispatch.packed_band`` returns them."""
+        g, ii, q = np.nonzero(pairs.mask & (band <= eps))
+        x_len = self.lam + ii
+        q_len = x_len + q - self.lambda0
+        cols = (pairs.seq[g], pairs.xs[g], x_len, pairs.qs[g], q_len)
+        order = np.lexsort(cols[::-1])
+        d = band[g, ii, q][order].tolist()
+        return [MatchPair(*k, dist) for k, dist in zip(
+            zip(*(c[order].tolist() for c in cols)), d)]
+
+
 class LinearScanIndex:
     """Counted linear scan over all windows — the naive baseline, and the
     only legal path for consistent-but-non-metric distances (DTW, §5)."""
@@ -134,6 +295,7 @@ class SubsequenceMatcher:
             _IndexTuning(eps_prime=eps_prime, num_max=num_max,
                          tight_bounds=tight_bounds, mv_refs=mv_refs)))
         self.seqs: List[np.ndarray] = []
+        self.scope: Optional[MatchScope] = None
         self.windows: Optional[np.ndarray] = None
         self.meta: List[seg.Window] = []
         self.index = None
@@ -154,7 +316,8 @@ class SubsequenceMatcher:
         ``dispatch_count`` report query work only.
         """
         self.seqs = [np.asarray(x) for x in seqs]
-        self.windows, self.meta = seg.partition_windows(self.seqs, self.lam)
+        self.scope = MatchScope(self.seqs, self.lam, self.lambda0)
+        self.windows, self.meta = self.scope.windows, self.scope.meta
         counter = CountedDistance(self.dist, self.windows,
                                   backend=self.backend,
                                   kernel_exec=self.kernel_exec,
@@ -241,28 +404,18 @@ class SubsequenceMatcher:
 
     # -- step 5: candidate generation + verification ---------------------------
 
-    def _candidates_for_hit(self, Q: np.ndarray, hit: SegmentHit
-                            ) -> List[Tuple[int, int, int, int, int]]:
-        """Candidate (seq_id, xs, xe, qs, qe) around one hit (paper §7)."""
-        l, lam, l0 = self.l, self.lam, self.lambda0
-        a = hit.segment.start
-        b = hit.segment.start + hit.segment.length  # exclusive end
-        c = hit.window.start
-        X = self.seqs[hit.window.seq_id]
-        nQ, nX = len(Q), len(X)
-        out = []
-        for qs in range(max(0, a - l - l0), a + 1):
-            for qe in range(b, min(nQ, b + l + l0) + 1):
-                qlen = qe - qs
-                if qlen < lam:
-                    continue
-                for xs in range(max(0, c - l), c + 1):
-                    for xe in range(c + l, min(nX, c + lam) + 1):
-                        xlen = xe - xs
-                        if xlen < lam or abs(xlen - qlen) > l0:
-                            continue
-                        out.append((hit.window.seq_id, xs, xe, qs, qe))
-        return out
+    def _candidates(self, Q: np.ndarray, hits: Sequence[SegmentHit]
+                    ) -> List[Tuple[int, int, int, int, int]]:
+        """Candidate (seq_id, xs, xe, qs, qe) around the hits (paper §7),
+        sorted: the served path's envelope (``MatchScope.candidates``)."""
+        pairs = self.scope.candidates(
+            len(Q), [h.segment.start for h in hits],
+            [h.segment.length for h in hits], [h.window_idx for h in hits])
+        g, ii, q = np.nonzero(pairs.mask)
+        xe = pairs.xs[g] + self.lam + ii
+        qe = pairs.qs[g] + self.lam + ii + q - self.lambda0
+        return sorted(zip(pairs.seq[g].tolist(), pairs.xs[g].tolist(),
+                          xe.tolist(), pairs.qs[g].tolist(), qe.tolist()))
 
     def _verify(self, Q: np.ndarray, cands: Sequence[Tuple[int, int, int, int, int]]
                 ) -> List[MatchPair]:
@@ -292,8 +445,7 @@ class SubsequenceMatcher:
     def query_range(self, Q: np.ndarray, eps: float) -> List[MatchPair]:
         Q = np.asarray(Q)
         hits = self.segment_hits(Q, eps)
-        cands = sorted({c for h in hits for c in self._candidates_for_hit(Q, h)})
-        verified = self._verify(Q, cands)
+        verified = self._verify(Q, self._candidates(Q, hits))
         return [m for m in verified if m.distance <= eps]
 
     # -- query type II ----------------------------------------------------------
@@ -425,9 +577,8 @@ class SubsequenceMatcher:
         best: Optional[MatchPair] = None
         while best is None and eps <= eps_max + 1e-9:
             hits = self.segment_hits(Q, eps)
-            cands = sorted({c for h in hits
-                            for c in self._candidates_for_hit(Q, h)})
-            verified = [m for m in self._verify(Q, cands)
+            verified = [m for m in self._verify(
+                Q, self._candidates(Q, hits))
                         if m.q_len >= self.lam and m.x_len >= self.lam]
             if verified:
                 cand_best = min(verified, key=lambda m: m.distance)

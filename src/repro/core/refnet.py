@@ -531,10 +531,23 @@ class ReferenceNet:
         returns the sorted hit list.  The frontier sequence — and therefore
         the exact-evaluation count — is identical to the classic host path;
         only *who* evaluates a frontier (sequential driver vs the batched
-        engine merging many plans per round) changes.
+        engine merging many plans per round) changes.  It is
+        :meth:`range_query_rows_plan` of one query row.
+        """
+        return (yield from self.range_query_rows_plan(eps, 1))[0]
+
+    def range_query_rows_plan(self, eps: float, rows: int
+                              ) -> batch_engine.Plan:
+        """Algorithm 3 for ``rows`` query rows at once (one row for a range
+        query, the segments of a subsequence-match request on one shard):
+        every frontier carries each candidate's query row
+        (``Frontier.rows``), and the plan returns one sorted hit list per
+        row.  Each row's frontiers and hits are those of a plan of its
+        own; a level's step runs once for all rows, so the host pays one
+        generator step per level, not one per row.
 
         The plan walks :meth:`plan_snapshot`'s levels from the top, one
-        level per step, with its state in arrays over the net's rows:
+        level per step, with its state in ``(rows, net rows)`` arrays:
         ``dist``/``known`` (each distance counted once), the Lemma-4
         intervals ``lo``/``hi`` (object) and ``slo``/``shi`` (subtree),
         ``decided``/``inside`` (object verdicts), ``reached`` (a parent
@@ -550,64 +563,82 @@ class ReferenceNet:
         parent's links fold into the children's intervals at once
         (``np.maximum.at`` / ``np.minimum.at``); as the bounds are valid
         intervals of a metric, no verdict depends on the order the
-        parents were visited in.
+        parents were visited in.  The state is indexed flat, row-major, so
+        one row's entries keep the order a single plan gives them.
         """
         self.plans += 1
-        if self.root is None:
-            return []
+        P = int(rows)
+        if self.root is None or not P:
+            return [[] for _ in range(P)]
         snap = self.plan_snapshot()
         n = snap.rows
-        dist = np.zeros(n)
-        known = np.zeros(n, bool)
-        lo = np.zeros(n)                 # object lower bounds
-        hi = np.full(n, INF)             # object upper bounds
-        slo = np.zeros(n)                # subtree lower bounds
-        shi = np.full(n, INF)            # subtree upper bounds
-        decided = np.zeros(n, bool)      # object verdict settled
-        inside = np.zeros(n, bool)       # ... and its value
-        reached = np.zeros(n, bool)      # expanded onto by a parent
-        settled = np.zeros(n, bool)      # whole-subtree verdict settled
-        accept = np.zeros(n, bool)       # ... and its value
+        dist = np.zeros(P * n)
+        known = np.zeros(P * n, bool)
+        lo = np.zeros(P * n)             # object lower bounds
+        hi = np.full(P * n, INF)         # object upper bounds
+        slo = np.zeros(P * n)            # subtree lower bounds
+        shi = np.full(P * n, INF)        # subtree upper bounds
+        decided = np.zeros(P * n, bool)  # object verdict settled
+        inside = np.zeros(P * n, bool)   # ... and its value
+        reached = np.zeros(P * n, bool)  # expanded onto by a parent
+        settled = np.zeros(P * n, bool)  # whole-subtree verdict settled
+        accept = np.zeros(P * n, bool)   # ... and its value
 
-        def request(idxs: np.ndarray, kind: str):
-            # callers pass sorted ids that are not yet known: ONE frontier
-            ds = yield batch_engine.Frontier(idxs, kind)
-            dist[idxs] = np.asarray(ds, np.float64)
-            known[idxs] = True
-            fresh = idxs[~decided[idxs]]
+        def request(f: np.ndarray, kind: str):
+            # callers pass ids that are not yet known, sorted within each
+            # row and rows ascending: ONE frontier
+            ds = yield batch_engine.Frontier(f % n, kind, rows=f // n)
+            dist[f] = np.asarray(ds, np.float64)
+            known[f] = True
+            fresh = f[~decided[f]]
             inside[fresh] = dist[fresh] <= eps
             decided[fresh] = True
+
+        def holders(ids: np.ndarray, on: np.ndarray,
+                    off: Optional[np.ndarray] = None):
+            # (row offset, holder position) pairs of a level's list
+            # holders with ``on`` set (and ``off`` clear), read on the
+            # level's columns only
+            m = on.reshape(P, n)[:, ids]
+            if off is not None:
+                m &= ~off.reshape(P, n)[:, ids]
+            p, pos = np.nonzero(m)
+            return p * n, pos
+
+        def spread(base, sel, cnt):
+            # each selected holder's row offset, once per link
+            return np.repeat(base[sel], cnt)
 
         def pass_down(level: int) -> None:
             # settled flags of this level's list holders onto their members
             ids, ptr, child, _ = snap.levels[level]
-            pos = np.flatnonzero(settled[ids])
+            base, pos = holders(ids, settled)
             if not pos.size:
                 return
             e, cnt = _links(ptr, pos)
-            c = child[e]
+            c = spread(base, slice(None), cnt) + child[e]
             new = ~settled[c]
             settled[c[new]] = True
-            accept[c[new]] = np.repeat(accept[ids[pos]], cnt)[new]
+            accept[c[new]] = np.repeat(accept[base + ids[pos]], cnt)[new]
 
-        root = np.asarray([self.root], np.int64)
-        yield from request(root, batch_engine.EXACT)
-        reached[root] = True
+        yield from request(np.arange(P) * n + self.root,
+                           batch_engine.EXACT)
+        reached[np.arange(P) * n + self.root] = True
         for level in range(self.top_level, -1, -1):
             if level < self.top_level:
                 pass_down(level + 1)
             ids, ptr, child, link_r = snap.levels[level]
-            pos = np.flatnonzero(reached[ids] & ~settled[ids])
+            base, pos = holders(ids, reached, settled)
             if not pos.size:
                 continue
             # evaluate the expandable nodes whose level is reached; exact
             # values feed Lemma-4 bound propagation below
-            par = ids[pos]
+            par = base + ids[pos]
             defer = par[~known[par]]
             if defer.size:
                 yield from request(defer, batch_engine.EXACT)
             d = dist[par]
-            sr = snap.sub_radius[par]
+            sr = snap.sub_radius[ids[pos]]
             whole_in = d + sr <= eps
             whole_out = ~whole_in & (d - sr > eps)
             # a parent's own verdict is exact already; whole_in settles its
@@ -615,13 +646,13 @@ class ReferenceNet:
             settled[par[whole_in]] = True
             accept[par[whole_in]] = True
             if whole_out.any():
-                c = child[_links(ptr, pos[whole_out])[0]]
-                settled[c] = True
+                e, cnt = _links(ptr, pos[whole_out])
+                settled[spread(base, whole_out, cnt) + child[e]] = True
             expand = ~(whole_in | whole_out)
             if not expand.any():
                 continue
             e, cnt = _links(ptr, pos[expand])
-            c = child[e]
+            c = spread(base, expand, cnt) + child[e]
             keep = ~settled[c]
             c = c[keep]
             if not c.size:
@@ -630,7 +661,7 @@ class ReferenceNet:
             r = link_r[e[keep]]
             lo_e = dp - r
             hi_e = dp + r
-            src = snap.sub_radius[c]
+            src = snap.sub_radius[c % n]
             np.maximum.at(lo, c, lo_e)
             np.minimum.at(hi, c, hi_e)
             np.maximum.at(slo, c, lo_e - src)
@@ -652,12 +683,13 @@ class ReferenceNet:
 
         # final object verdicts for leaves no parent managed to decide free;
         # only the <= eps verdict is consumed, so the LB cascade may prune
-        rem = np.flatnonzero(reached & ~snap.has_children & ~decided
-                             & ~settled & ~known)
+        leaf = np.tile(~snap.has_children, P)
+        rem = np.flatnonzero(reached & leaf & ~decided & ~settled & ~known)
         if rem.size:
             yield from request(rem, batch_engine.VERDICT)
-        hit = np.where(decided, inside, settled & accept)
-        return np.flatnonzero(hit).tolist()
+        hit = np.flatnonzero(np.where(decided, inside, settled & accept))
+        cut = np.searchsorted(hit, np.arange(1, P) * n)
+        return [h.tolist() for h in np.split(hit % n, cut)]
 
     def _subtree(self, n: int, include_self: bool = True) -> List[int]:
         out = [n] if include_self else []

@@ -97,29 +97,34 @@ def pack_meta(lx: np.ndarray, ly: np.ndarray
 
     Returns the sort order plus the static bucket metadata of the sorted
     layout."""
-    order = np.lexsort((ly, lx))
+    if not len(lx):
+        return np.zeros(0, np.int64), PackedMeta((), ())
+    # one small-integer key, so the stable sort is a radix sort
+    key = np.asarray(lx, np.int64) * (int(ly.max()) + 1) + ly
+    order = np.argsort(key.astype(np.min_scalar_type(key.max())),
+                       kind="stable")
     slx, sly = lx[order], ly[order]
-    buckets, offsets = [], []
-    start = 0
-    for i in range(1, len(order) + 1):
-        if i == len(order) or slx[i] != slx[start] or sly[i] != sly[start]:
-            buckets.append((int(slx[start]), int(sly[start]), i - start))
-            offsets.append(start)
-            start = i
-    return order, PackedMeta(tuple(buckets), tuple(offsets))
+    starts = np.r_[0, np.flatnonzero((slx[1:] != slx[:-1])
+                                     | (sly[1:] != sly[:-1])) + 1]
+    counts = np.diff(np.r_[starts, len(order)])
+    return order, PackedMeta(
+        tuple(zip(slx[starts].tolist(), sly[starts].tolist(),
+                  counts.tolist())), tuple(starts.tolist()))
 
 
 def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
-                 block_b: int = 8, interpret: Optional[bool] = None,
-                 exec: Optional[str] = None, tile: Optional[int] = None
-                 ) -> registry.KernelOut:
+                 block_b: Optional[int] = None,
+                 interpret: Optional[bool] = None,
+                 exec: Optional[str] = None, tile: Optional[int] = None,
+                 trim: bool = True) -> registry.KernelOut:
     """ONE padded device call over every length bucket of a round.
 
     ``xs``/``ys`` are row-paired batches whose rows may come from different
     ``(len_x, len_y)`` buckets (``lx``/``ly`` carry the actual lengths);
     ``eps`` (scalar or per-row; +inf rows opt out) enables fused ε-pruning.
     ``exec``/``tile`` pick the wavefront execution mode and Pallas band
-    depth (None: the registry's process-wide policy / VMEM heuristic).
+    depth (None: the registry's process-wide policy / VMEM heuristic);
+    ``trim=False`` keeps the caller's padded widths as the shape class.
     Results come back in the caller's row order as numpy arrays.
     """
     spec = registry.get(name)
@@ -142,7 +147,7 @@ def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
         if eps_v is not None:
             eps_v = eps_v[order]
     out = spec.batch(xs, ys, lx, ly, eps=eps_v, block_b=block_b,
-                     interpret=interpret, exec=exec, tile=tile)
+                     interpret=interpret, exec=exec, tile=tile, trim=trim)
 
     with spans.span(spans.DISPATCH_UNPACK):
         inv = np.empty_like(order)
@@ -153,6 +158,37 @@ def packed_batch(name: str, xs, ys, lx=None, ly=None, *, eps=None,
     STATS.rows += B
     STATS.last_meta = meta
     return result
+
+
+def packed_band(name: str, xs, ys, lx, ly, *, lam: int, lambda0: int,
+                eps, block_b: Optional[int] = None,
+                interpret: Optional[bool] = None,
+                exec: Optional[str] = None,
+                tile: Optional[int] = None) -> np.ndarray:
+    """ONE band-mode call over start-pair rows (the verify stage of
+    subsequence matching): row ``r`` aligns ``xs[r, :lx[r]]`` with
+    ``ys[r, :ly[r]]`` from their first elements, and comes back as
+    ``(B, Lx-lam+1, 2*lambda0+1)``: ``D[i, i + q - lambda0]`` at
+    ``[r, i - lam, q]``, ``+inf`` where that cell does not exist or
+    exceeds the row's ``eps``.  The operands' padded widths are the shape
+    class (no trim), so a caller that pads to fixed widths compiles one
+    class per power-of-two batch.  :data:`STATS` is left alone: it counts
+    :func:`packed_batch`'s calls, and ``last_meta`` stays the latest
+    merged filter round's."""
+    spec = registry.get(name)
+    xs = np.asarray(xs)
+    B = len(xs)
+    if B == 0:
+        return np.zeros((0, xs.shape[1] - lam + 1, 2 * lambda0 + 1),
+                        np.float32)
+    lx = np.asarray(lx, np.int64)
+    ly = np.asarray(ly, np.int64)
+    with spans.span(spans.DISPATCH_PACK, rows=B) as sp:
+        _, meta = pack_meta(lx, ly)
+        sp.set_metadata(buckets=meta.n_buckets)
+    return spec.batch(xs, ys, lx, ly, eps=eps, block_b=block_b,
+                      interpret=interpret, exec=exec, tile=tile,
+                      trim=False, band=(int(lam), int(lambda0)))
 
 
 def packed_envelope(name: str, xs, ys, lx=None, ly=None, *, eps,

@@ -81,6 +81,7 @@ class CacheKey(NamedTuple):
     interpret: bool
     exec: Optional[str]
     tile: Optional[int]
+    band: Optional[tuple] = None   # (lam, lambda0) in band mode
 
 
 _JIT_CACHE: Dict[CacheKey, object] = {}
@@ -184,7 +185,7 @@ def resolve_exec(exec_mode: Optional[str]) -> str:
 
 
 def default_tile(Lx: int, Ly: int, d: int, block_b: int = 8,
-                 budget: int = VMEM_TILE_BUDGET) -> int:
+                 budget: int = VMEM_TILE_BUDGET, nb: int = 0) -> int:
     """Deepest anti-diagonal band whose working set fits the VMEM budget.
 
     The working set is :func:`~repro.kernels.wavefront.vmem_bytes` (lane-
@@ -193,11 +194,11 @@ def default_tile(Lx: int, Ly: int, d: int, block_b: int = 8,
     budget.  Clamped to ``[8, Lx+Ly]`` — on short segments (every CI bench
     shape) the whole DP fits one band, which is exactly the untiled
     schedule; where even 8 diagonals overrun the budget the kernel raises
-    its own VMEM limit to match.
+    its own VMEM limit to match.  ``nb``: the band mode's band diagonals.
     """
     K = Lx + Ly
-    base = vmem_bytes(Lx, Ly, d, 0, block_b)
-    per_t = (vmem_bytes(Lx, Ly, d, 8 * K, block_b) - base) // (8 * K)
+    base = vmem_bytes(Lx, Ly, d, 0, block_b, nb)
+    per_t = (vmem_bytes(Lx, Ly, d, 8 * K, block_b, nb) - base) // (8 * K)
     T = (budget - base) // per_t
     return max(8, min(int(T), K))
 
@@ -211,6 +212,33 @@ def clear_cache() -> None:
 def cache_keys() -> list:
     """The shape classes compiled so far (what ran, and how)."""
     return list(_JIT_CACHE)
+
+
+def _nb(band: Optional[tuple]) -> int:
+    """Band diagonals of the band mode (0 outside it)."""
+    return 0 if band is None else 2 * band[1] + 1
+
+
+#: the most rows one device call takes: :meth:`KernelSpec.batch` sends a
+#: larger batch as calls of at most this many rows, so the batch classes
+#: any traffic reaches are the powers of two up to it, and a warm-up to
+#: it covers them all
+MAX_BATCH = 1 << 16
+
+
+#: a batch of at least this many (padded) rows takes :data:`WIDE_BLOCK`
+#: rows a Pallas grid step instead of one sublane tile of 8: on a v5e a
+#: 22 x 20 Levenshtein row at 65,536 rows took 1.67 us of device time at
+#: 8 rows a step and 0.81 us at 64 (16: 1.15, 32: 0.91).  Band-mode rows
+#: keep 8 (60 x 62 at 16,384 rows: 5.64 us at 8, 6.26 at 64).
+WIDE_BATCH = 1 << 15
+WIDE_BLOCK = 64
+
+
+def default_block(P: int, band: Optional[tuple] = None) -> int:
+    """Rows a grid step of the Pallas wavefront takes for a batch padded
+    to ``P`` rows (see :data:`WIDE_BATCH`)."""
+    return WIDE_BLOCK if P >= WIDE_BATCH and band is None else 8
 
 
 def _pad_pow2(n: int) -> int:
@@ -240,7 +268,8 @@ class KernelSpec:
     def device_call(self, xs, ys, lx=None, ly=None, eps=None, *,
                     block_b: int = 8, interpret: Optional[bool] = None,
                     exec: Optional[str] = None,
-                    tile: Optional[int] = None) -> KernelOut:
+                    tile: Optional[int] = None,
+                    band: Optional[tuple] = None) -> KernelOut:
         """Traceable batched evaluation -> :class:`KernelOut` of jnp arrays.
 
         ``xs``/``ys`` are row-paired ``(B, Lx[, d])`` / ``(B, Ly[, d])``
@@ -250,7 +279,10 @@ class KernelSpec:
         ``exec`` picks the wavefront execution mode (``pallas`` | ``scan``;
         None follows :func:`default_exec`) and ``tile`` the Pallas band
         depth (None: the :func:`default_tile` VMEM heuristic) — both only
-        apply to the wavefront specs.
+        apply to the wavefront specs.  ``band=(lam, lambda0)`` (wavefront
+        specs only) returns instead each row's end cells near its
+        diagonal, ``(B, Lx-lam+1, 2*lambda0+1)`` (``wavefront.wavefront_
+        pallas``), ``+inf`` where a cell does not exist or exceeds ε.
         """
         interpret = resolve_interpret(interpret)
         xs = jnp.asarray(xs)
@@ -262,13 +294,16 @@ class KernelSpec:
             else jnp.asarray(ly, jnp.int32)
         eps_v = jnp.full((B,), jnp.inf, jnp.float32) if eps is None \
             else jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (B,))
+        if band is not None and self.kind != "wavefront":
+            raise ValueError(f"{self.name!r} has no band mode")
         if self.kind == "elementwise":
             return self._elementwise(xs, ys, lx, eps_v)
         if self.kind == "envelope":
             return self._envelope(xs, ys, lx, ly, eps_v)
         return self._wavefront(xs, ys, lx, ly, eps_v, block_b=block_b,
                                interpret=interpret,
-                               exec_mode=resolve_exec(exec), tile=tile)
+                               exec_mode=resolve_exec(exec), tile=tile,
+                               band=band)
 
     def _elementwise(self, xs, ys, lx, eps_v) -> KernelOut:
         L = xs.shape[1]
@@ -349,7 +384,8 @@ class KernelSpec:
 
     def _wavefront(self, xs, ys, lx, ly, eps_v, *, block_b, interpret,
                    exec_mode: str = "pallas",
-                   tile: Optional[int] = None) -> KernelOut:
+                   tile: Optional[int] = None,
+                   band: Optional[tuple] = None) -> KernelOut:
         mode = self.mode
         xs = xs.astype(jnp.float32)  # lev tokens ride as exact small floats
         ys = ys.astype(jnp.float32)
@@ -410,34 +446,46 @@ class KernelSpec:
         if exec_mode == "scan":
             # compiled lax.scan twin: same layout, same per-diagonal math,
             # no batch blocking or banding (XLA owns the schedule)
-            dist, hit, pruned = wavefront_scan(
-                *args, mode=mode, Lx=Lx, Ly=Ly, d=d)
-            return KernelOut(dist, hit, pruned)
+            out = wavefront_scan(*args, mode=mode, Lx=Lx, Ly=Ly, d=d,
+                                 band=band)
+            return out if band is not None else KernelOut(*out)
         P = B + ((-B) % block_b)
         if P != B:
             args = [jnp.pad(a, [(0, P - B)] + [(0, 0)] * (a.ndim - 1))
                     for a in args]
         if tile is None:
-            tile = default_tile(Lx, Ly, d, block_b)
-        dist, hit, pruned = wavefront_pallas(
+            tile = default_tile(Lx, Ly, d, block_b, nb=_nb(band))
+        out = wavefront_pallas(
             *args, mode=mode, Lx=Lx, Ly=Ly, d=d, block_b=block_b,
-            interpret=interpret, tile=tile)
+            interpret=interpret, tile=tile, band=band)
+        if band is not None:
+            return out[:B]
+        dist, hit, pruned = out
         return KernelOut(dist[:B], hit[:B], pruned[:B])
 
     # -- host path (cached jit) ----------------------------------------------
 
     def batch(self, xs, ys, lx=None, ly=None, eps=None, *,
-              block_b: int = 8, interpret: Optional[bool] = None,
+              block_b: Optional[int] = None,
+              interpret: Optional[bool] = None,
               exec: Optional[str] = None,
-              tile: Optional[int] = None) -> KernelOut:
+              tile: Optional[int] = None, trim: bool = True,
+              band: Optional[tuple] = None) -> KernelOut:
         """Host entry: numpy in/out, shapes padded and jit-cached.
 
         ``lx``/``ly`` may mix length buckets freely; operands are trimmed
-        to the max actual lengths and the batch padded to a power of two so
-        the number of distinct compiled shapes stays bounded.  ``exec`` /
+        to the max actual lengths (``trim=False`` keeps the widths the
+        caller padded to, so its shape classes stay the caller's) and the
+        batch padded to a power of two so the number of distinct compiled
+        shapes stays bounded.  ``band`` returns the band mode's end cells,
+        a numpy ``(B, Lx-lam+1, 2*lambda0+1)`` array (see
+        :meth:`device_call`).  ``exec`` /
         ``tile`` select the wavefront execution mode and Pallas band depth
         (see :meth:`device_call`); both resolve to static values *before*
         the cache lookup, so each (shape, exec, tile) class compiles once.
+        More than :data:`MAX_BATCH` rows go as several calls of at most
+        that many, at the whole batch's widths.  ``block_b`` (rows a
+        Pallas grid step takes) follows :func:`default_block` where None.
         """
         xs = np.asarray(xs)
         ys = np.asarray(ys)
@@ -445,20 +493,27 @@ class KernelSpec:
         if B == 0:
             z = np.zeros((0,), np.float32)
             return KernelOut(z, z.astype(bool), z.astype(bool))
+        if B > MAX_BATCH:
+            return self._split(xs, ys, lx, ly, eps, trim=trim, band=band,
+                               block_b=block_b, interpret=interpret,
+                               exec=exec, tile=tile)
         with spans.span(spans.DISPATCH_PAD, rows=B) as sp:
             if lx is None:
                 lx = np.full(B, xs.shape[1], np.int32)
             else:
                 lx = np.asarray(lx, np.int32)
-                xs = xs[:, :max(int(lx.max()), 1)]
+                if trim:
+                    xs = xs[:, :max(int(lx.max()), 1)]
             if ly is None:
                 ly = np.full(B, ys.shape[1], np.int32)
             else:
                 ly = np.asarray(ly, np.int32)
-                ys = ys[:, :max(int(ly.max()), 1)]
+                if trim:
+                    ys = ys[:, :max(int(ly.max()), 1)]
             eps_v = np.full(B, np.inf, np.float32) if eps is None else \
                 np.broadcast_to(np.asarray(eps, np.float32), (B,))
-            P = _pad_pow2(max(B, block_b))
+            P = _pad_pow2(max(B, block_b or 8))
+            block_b = block_b or default_block(P, band)
             args = [_pad_rows(a, P) for a in (xs, ys, lx, ly, eps_v)]
             sp.set_metadata(
                 padded_rows=P, cells=int(np.dot(lx.astype(np.int64), ly)),
@@ -469,7 +524,7 @@ class KernelSpec:
                 if exec_mode == "pallas" and tile is None:
                     dim = xs.shape[2] if xs.ndim == 3 else 1
                     tile = default_tile(xs.shape[1], ys.shape[1], dim,
-                                        block_b)
+                                        block_b, nb=_nb(band))
                 if exec_mode == "scan":
                     tile = None  # no banding: one cache entry per shape
             else:
@@ -477,17 +532,43 @@ class KernelSpec:
         with spans.span(spans.DISPATCH_LAUNCH,
                         h2d_bytes=sum(a.nbytes for a in args)):
             fn = self._cached(xs, ys, P, block_b, interpret, exec_mode,
-                              tile)
-            d, h, p = fn(*args)
+                              tile, band)
+            out = fn(*args)
+        if band is not None:
+            with spans.span(spans.DISPATCH_FETCH, d2h_bytes=out.nbytes):
+                return np.asarray(out)[:B]
+        d, h, p = out
         with spans.span(spans.DISPATCH_FETCH,
                         d2h_bytes=d.nbytes + h.nbytes + p.nbytes):
             return KernelOut(np.asarray(d)[:B], np.asarray(h)[:B],
                              np.asarray(p)[:B])
 
+    def _split(self, xs, ys, lx, ly, eps, *, trim, band, **kw):
+        """:meth:`batch` over more than :data:`MAX_BATCH` rows, as calls
+        of at most that many rows at the whole batch's widths."""
+        B = len(xs)
+        lx = np.full(B, xs.shape[1]) if lx is None else np.asarray(lx)
+        ly = np.full(B, ys.shape[1]) if ly is None else np.asarray(ly)
+        if trim:
+            xs = xs[:, :max(int(lx.max()), 1)]
+            ys = ys[:, :max(int(ly.max()), 1)]
+        eps = None if eps is None else \
+            np.broadcast_to(np.asarray(eps, np.float32), (B,))
+        # lint: allow[dispatch-in-loop] -- the cap on one call's rows: each part is a full MAX_BATCH class but the last, so the warmed classes cover any batch
+        parts = [self.batch(xs[c], ys[c], lx[c], ly[c],
+                            None if eps is None else eps[c], trim=False,
+                            band=band, **kw)
+                 for c in (slice(i, i + MAX_BATCH)
+                           for i in range(0, B, MAX_BATCH))]
+        if band is not None:
+            return np.concatenate(parts)
+        return KernelOut(*(np.concatenate(f) for f in zip(*parts)))
+
     def _cached(self, xs, ys, P, block_b, interpret, exec_mode=None,
-                tile=None):
+                tile=None, band=None):
         key = CacheKey(self.name, xs.shape[1:], str(xs.dtype), ys.shape[1:],
-                       str(ys.dtype), P, block_b, interpret, exec_mode, tile)
+                       str(ys.dtype), P, block_b, interpret, exec_mode, tile,
+                       band)
         fn = _JIT_CACHE.get(key)
         if fn is None:
             spec = self
@@ -496,7 +577,7 @@ class KernelSpec:
                 STATS["traces"] += 1  # python side effect: runs per (re)trace
                 return spec.device_call(xs, ys, lx, ly, eps,
                                         block_b=block_b, interpret=interpret,
-                                        exec=exec_mode, tile=tile)
+                                        exec=exec_mode, tile=tile, band=band)
 
             fn = jax.jit(traced)
             _JIT_CACHE[key] = fn

@@ -35,6 +35,12 @@ the answer ``D[len_x, len_y]`` is recorded on the fly when diagonal
 outside a row's actual problem compute padding garbage that never feeds
 its answer cell (DP dependencies only point to smaller indices).
 
+Band mode (``band=(lam, lambda0)``): a row is a start pair of the paper's
+step-5 verification, and its answer is every end cell ``(i, j)`` with
+``i, j >= lam`` and ``|i - j| <= lambda0`` inside its lengths, recorded
+the same way as its diagonal passes — one DP per start pair, however many
+candidate pairs share it.
+
 Fused ε-pruning: each row also carries an ``eps`` threshold.  All four
 distances are monotone along alignment paths (every combine adds a
 nonnegative cost or takes a max), and any monotone path touches at least
@@ -61,7 +67,7 @@ registry (``kernels/registry.py``) owns the jit cache over them.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,14 +81,17 @@ def _tiles(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def vmem_bytes(Lx: int, Ly: int, d: int, tile: int, block_b: int = 8) -> int:
+def vmem_bytes(Lx: int, Ly: int, d: int, tile: int, block_b: int = 8,
+               nb: int = 0) -> int:
     """Upper bound on the banded kernel's VMEM working set, as the chip lays
     it out: a block's last axis is padded to 128 lanes and the one before
     it to 8 sublanes, so a ``(block_b, W, d)`` block costs ``W*128`` words
     per row however small ``d`` is.  Counts the double-buffered x and
     band-y blocks (y with one ERP gap channel), four in-loop temporaries
     of the diagonal window's shape, the double-buffered 2-D operands and
-    outputs, and the carry scratch.  Only the y band grows with ``tile``.
+    outputs, and the carry scratch; in band mode (``nb`` band diagonals)
+    also the band's carry scratch, its double-buffered output block and
+    one temporary per band diagonal.  Only the y band grows with ``tile``.
     """
     W = Lx + 1
     lanes_x = _tiles(d, 128)
@@ -92,7 +101,8 @@ def vmem_bytes(Lx: int, Ly: int, d: int, tile: int, block_b: int = 8) -> int:
              + 2 * block_b * _tiles(Lx + tile, 8) * lanes_y
              + 4 * block_b * _tiles(W, 8) * lanes_y
              + 2 * rows * (2 * _tiles(W, 128) + _tiles(Ly + 1, 128) + 5 * 128)
-             + rows * (2 * _tiles(W, 128) + 2 * 128))
+             + rows * (2 * _tiles(W, 128) + 2 * 128)
+             + 4 * nb * rows * _tiles(W, 128))
     return 4 * words
 
 
@@ -100,7 +110,8 @@ def _shift_right(v, fill):
     return jnp.concatenate([jnp.full_like(v[:, :1], fill), v[:, :-1]], axis=1)
 
 
-def _make_step(mode: str, Lx: int, Ly: int, d: int):
+def _make_step(mode: str, Lx: int, Ly: int, d: int,
+               band: Optional[Tuple[int, int]] = None):
     """One anti-diagonal DP update — the single source of the per-step math.
 
     Every execution mode (tiled Pallas, interpret-mode Pallas, compiled
@@ -108,10 +119,16 @@ def _make_step(mode: str, Lx: int, Ly: int, d: int):
     the parity gates compare equality, not tolerance.  ``carry`` is
     ``(d1, d2, res, alive)``: the two rolling diagonals, the recorded
     answers, and the fused-ε liveness mask (f32 0/1 so it can ride VMEM
-    scratch).  ``yw`` is diagonal ``k``'s ``(B, Lx+1, dy)`` reversed-y
-    window, already sliced by the caller (full-layout or band-tile offsets
-    — the only thing that differs between execution modes); for ERP its
-    last channel is the reversed gap cost of y (``dy = d + 1``).
+    scratch).  ``res`` is each row's ``D[len_x, len_y]``, or in band mode
+    (``band=(lam, lambda0)``) a tuple of ``2*lambda0 + 1`` ``(B, Lx+1)``
+    arrays: entry ``q`` holds ``D[i, i + q - lambda0]`` at position ``i``
+    for every end cell with ``lam <= i <= len_x`` and
+    ``lam <= j <= len_y``, recorded as its diagonal passes (see
+    :func:`_record_band`).  ``yw`` is diagonal ``k``'s ``(B, Lx+1, dy)``
+    reversed-y window, already sliced by the caller (full-layout or
+    band-tile offsets — the only thing that differs between execution
+    modes); for ERP its last channel is the reversed gap cost of y
+    (``dy = d + 1``).
 
     Every lookup by the traced diagonal index ``k`` is an iota select (and
     a select-sum over one nonzero term, which is exact): Mosaic lowers
@@ -151,9 +168,13 @@ def _make_step(mode: str, Lx: int, Ly: int, d: int):
         new = jnp.where(ii == 0, jnp.where(k <= Ly, rowv, BIG), new)
         # outside the valid band
         new = jnp.where((ii > k) | (ii < k - Ly), BIG, new)
-        # record each row's answer when its target diagonal passes
-        val = jnp.sum(jnp.where(ii == lx, new, 0.0), axis=1, keepdims=True)
-        res = jnp.where(target == k, val, res)
+        if band is None:
+            # record each row's answer when its target diagonal passes
+            val = jnp.sum(jnp.where(ii == lx, new, 0.0), axis=1,
+                          keepdims=True)
+            res = jnp.where(target == k, val, res)
+        else:
+            res = _record_band(k, new, res, ii, lx, target, band)
         # fused ε certificate: every monotone path touches one of any two
         # consecutive diagonals, so both exceeding eps bounds the final
         rowmin = jnp.min(jnp.minimum(new, d1), axis=1, keepdims=True)
@@ -163,14 +184,41 @@ def _make_step(mode: str, Lx: int, Ly: int, d: int):
     return step
 
 
-def _init_carry(bc, target):
+def _record_band(k, new, res, ii, lx, target, band):
+    """Band mode: keep diagonal ``k``'s end cells.  Cell ``(i, j)`` lies on
+    diagonal ``i + j``, so band entry ``q`` (``j = i + q - lambda0``)
+    takes position ``i`` when ``k - 2i == q - lambda0``; cells outside
+    ``[lam, len_x] x [lam, len_y]`` are never written and keep ``BIG``."""
+    lam, l0 = band
+    j = k - ii
+    live = (ii >= lam) & (ii <= lx) & (j >= lam) & (j <= target - lx)
+    return tuple(jnp.where(live & (j - ii == q - l0), new, r)
+                 for q, r in enumerate(res))
+
+
+def _init_carry(bc, target, band=None):
     """Diagonal 0 holds only ``D[0,0] = bc[:, 0]``; diagonal -1 is empty."""
     ii = jax.lax.broadcasted_iota(jnp.int32, bc.shape, 1)
     diag0 = jnp.where(ii == 0, bc, BIG)
+    if band is None:
+        res = jnp.where(target == 0, bc[:, 0:1], BIG)
+    else:
+        res = tuple(jnp.full(bc.shape, BIG, jnp.float32)
+                    for _ in range(2 * band[1] + 1))
     return (diag0,
             jnp.full(bc.shape, BIG, jnp.float32),
-            jnp.where(target == 0, bc[:, 0:1], BIG),
+            res,
             jnp.ones(target.shape, jnp.float32))
+
+
+def _band_out(raw, eps, lam: int):
+    """``(nb, B, Lx+1)`` recorded band -> ``(B, Lx-lam+1, nb)``: entry
+    ``[r, i - lam, q]`` is ``D[i, i + q - lambda0]`` of row ``r``, ``+inf``
+    where the cell does not exist or exceeds the row's ``eps`` (fused ε:
+    a cell above it is never needed, so it is never returned)."""
+    v = jnp.moveaxis(raw[:, :, lam:], 0, 2)
+    keep = (v < BIG) & (v <= eps[:, :, None])
+    return jnp.where(keep, v, jnp.inf)
 
 
 def band_layout(y_rev_pad, Lx: int, Ly: int, tile: int):
@@ -203,14 +251,27 @@ def band_layout(y_rev_pad, Lx: int, Ly: int, tile: int):
 
 
 def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
-                 nbands: int):
+                 nbands: int, band: Optional[Tuple[int, int]] = None):
     W = Lx + 1
     K = Lx + Ly
-    step = _make_step(mode, Lx, Ly, d)
+    step = _make_step(mode, Lx, Ly, d, band)
+    n_out = 3 if band is None else 1
+    nb = 0 if band is None else 2 * band[1] + 1
+
+    def load_res(ref):
+        return ref[...] if band is None else tuple(ref[q] for q in range(nb))
+
+    def store_res(ref, res):
+        if band is None:
+            ref[...] = res
+        else:
+            for q in range(nb):
+                ref[q] = res[q]
 
     def kernel(x_ref, yb_ref, gx_ref, bc_ref, br_ref, lens_ref, eps_ref,
-               out_ref, hit_ref, prune_ref,
-               d1_ref, d2_ref, res_ref, alive_ref):
+               *refs):
+        outs = refs[:n_out]
+        d1_ref, d2_ref, res_ref, alive_ref = refs[n_out:]
         x = x_ref[...]          # (Bt, W, d)   x[i] = x_orig[i-1]
         gx = gx_ref[...]        # (Bt, W)      ERP gap cost of x_i (else 0)
         bc = bc_ref[...]        # (Bt, Lx+1)   border column D[i,0]
@@ -226,10 +287,10 @@ def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
         # scratch accumulators persist across a batch block's grid cells)
         @pl.when(j == 0)
         def _init():
-            d1, d2, res, alive = _init_carry(bc, target)
+            d1, d2, res, alive = _init_carry(bc, target, band)
             d1_ref[...] = d1
             d2_ref[...] = d2
-            res_ref[...] = res
+            store_res(res_ref, res)
             alive_ref[...] = alive
 
         def body(t, carry):
@@ -242,17 +303,22 @@ def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
             return jax.tree_util.tree_map(
                 lambda n, o: jnp.where(k <= K, n, o), out, carry)
 
-        carry = (d1_ref[...], d2_ref[...], res_ref[...], alive_ref[...])
+        carry = (d1_ref[...], d2_ref[...], load_res(res_ref),
+                 alive_ref[...])
         d1, d2, res, alive = jax.lax.fori_loop(0, tile, body, carry)
         d1_ref[...] = d1
         d2_ref[...] = d2
-        res_ref[...] = res
+        store_res(res_ref, res)
         alive_ref[...] = alive
 
         # prune verdicts are only emitted at a band boundary — here, the
         # final one; the certificate itself rides the scratch accumulator
         @pl.when(j == nbands - 1)
         def _emit():
+            if band is not None:
+                store_res(outs[0], res)
+                return
+            out_ref, hit_ref, prune_ref = outs
             hit = res <= eps
             out_ref[...] = jnp.where(hit, res, BIG)
             hit_ref[...] = hit.astype(jnp.float32)
@@ -263,7 +329,8 @@ def _make_kernel(mode: str, Lx: int, Ly: int, d: int, tile: int,
 
 def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
                      eps, *, mode, Lx, Ly, d, block_b, interpret,
-                     tile: Optional[int] = None):
+                     tile: Optional[int] = None,
+                     band: Optional[Tuple[int, int]] = None):
     """Run the banded kernel on pre-laid-out inputs (traceable — the
     registry owns jit caching; see ``registry.KernelSpec.device_call``).
 
@@ -276,6 +343,12 @@ def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
     default limit.  Returns ``(dist, hit, pruned)`` as (B,) float32
     arrays: masked distances (``BIG`` where the verdict is a miss), the
     hit mask, and the early-prune certificate mask.
+
+    Band mode (``band=(lam, lambda0)``, the ``pallas_call`` named
+    ``wavefront_<mode>_band``) returns instead every end cell of each
+    row's DP near its diagonal, ``(B, Lx-lam+1, 2*lambda0+1)`` (see
+    :func:`_band_out`): one DP per start pair answers all of its
+    subsequence pairs.
     """
     B = x_pad.shape[0]
     W = Lx + 1
@@ -284,10 +357,21 @@ def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
     nbands = -(-K // T)
     Wb = Lx + T
     dy = y_rev_pad.shape[2]
+    nb = 0 if band is None else 2 * band[1] + 1
     y_bands = band_layout(y_rev_pad, Lx, Ly, T)    # (nbands, B, Wb, dy)
     grid = (B // block_b, nbands)
-    kernel = _make_kernel(mode, Lx, Ly, d, T, nbands)
+    kernel = _make_kernel(mode, Lx, Ly, d, T, nbands, band)
     row = lambda b, j: (b, 0)  # noqa: E731 — per-batch-block 2-D operands
+    if band is None:
+        out_specs = [pl.BlockSpec((block_b, 1), row)] * 3
+        out_shape = [jax.ShapeDtypeStruct((B, 1), jnp.float32)] * 3
+        res_scratch = pltpu.VMEM((block_b, 1), jnp.float32)
+        name = f"wavefront_{mode}"
+    else:
+        out_specs = [pl.BlockSpec((nb, block_b, W), lambda b, j: (0, b, 0))]
+        out_shape = [jax.ShapeDtypeStruct((nb, B, W), jnp.float32)]
+        res_scratch = pltpu.VMEM((nb, block_b, W), jnp.float32)
+        name = f"wavefront_{mode}_band"
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -301,25 +385,29 @@ def wavefront_pallas(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
             pl.BlockSpec((block_b, 2), row),
             pl.BlockSpec((block_b, 1), row),
         ],
-        out_specs=[pl.BlockSpec((block_b, 1), row)] * 3,
-        out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.float32)] * 3,
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_b, W), jnp.float32),   # carry diagonal k-1
             pltpu.VMEM((block_b, W), jnp.float32),   # carry diagonal k-2
-            pltpu.VMEM((block_b, 1), jnp.float32),   # recorded answers
+            res_scratch,                             # recorded answers
             pltpu.VMEM((block_b, 1), jnp.float32),   # fused-ε liveness
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_bytes(Lx, Ly, d, T, block_b) + (1 << 20)),
+            vmem_limit_bytes=vmem_bytes(Lx, Ly, d, T, block_b, nb)
+            + (1 << 20)),
         interpret=interpret,
-        name=f"wavefront_{mode}",
+        name=name,
     )(x_pad, y_bands, gap_x, border_col, border_row, lens, eps)
+    if band is not None:
+        return _band_out(outs[0], eps, band[0])
     dist, hit, pruned = outs
     return dist[:, 0], hit[:, 0] > 0, pruned[:, 0] > 0
 
 
 def wavefront_scan(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
-                   eps, *, mode, Lx, Ly, d):
+                   eps, *, mode, Lx, Ly, d,
+                   band: Optional[Tuple[int, int]] = None):
     """Compiled ``lax.scan`` wavefront — the registry's ``exec="scan"``
     execution mode.
 
@@ -329,14 +417,15 @@ def wavefront_scan(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
     batch blocking.  On CPU CI this is the measured device-path win (the
     interpret-mode Pallas emulation is parity theater); on TPU the banded
     Pallas kernel owns the hot path.  Returns the same ``(dist, hit,
-    pruned)`` triple, bit-identical to the Pallas schedules.
+    pruned)`` triple, bit-identical to the Pallas schedules, or in band
+    mode the same band block.
     """
     B = x_pad.shape[0]
     W = Lx + 1
     dy = y_rev_pad.shape[2]
     lx = lens[:, 0:1]
     target = lx + lens[:, 1:2]
-    step = _make_step(mode, Lx, Ly, d)
+    step = _make_step(mode, Lx, Ly, d, band)
 
     def body(carry, k):
         s = Lx + 1 + Ly - k  # start of the diagonal window in reversed y
@@ -345,7 +434,10 @@ def wavefront_scan(x_pad, y_rev_pad, gap_x, border_col, border_row, lens,
                     lx, target, eps), None
 
     (_, _, res, alive), _ = jax.lax.scan(
-        body, _init_carry(border_col, target), jnp.arange(1, Lx + Ly + 1))
+        body, _init_carry(border_col, target, band),
+        jnp.arange(1, Lx + Ly + 1))
+    if band is not None:
+        return _band_out(jnp.stack(res), eps, band[0])
     hit = res <= eps
     dist = jnp.where(hit, res, BIG)
     return dist[:, 0], hit[:, 0] > 0, alive[:, 0] < 0.5

@@ -397,10 +397,12 @@ class ElasticIndex:
             from repro.kernels.dispatch import packed_batch
 
             def evaluate(xs, ys, lx, ly, eps_rows):
+                # the gathered widths are the shape class: a round of
+                # segment rows keeps its widest segment's width
                 out = packed_batch(self.dist.name, xs, ys, lx, ly,
                                    eps=eps_rows, interpret=self.interpret,
                                    exec=self.kernel_exec,
-                                   tile=self.kernel_tile)
+                                   tile=self.kernel_tile, trim=False)
                 return (np.asarray(out.dist, np.float32),
                         int(np.asarray(out.pruned).sum()))
 

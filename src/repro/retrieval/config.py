@@ -13,7 +13,8 @@ distance       registry name (or ``Distance`` instance) — §4 consistency /
                metricity requirements are checked here
 lam, lambda0   subsequence-matching scope (§3.2).  ``lam=None`` = plain
                window-level retrieval over the database rows; ``lam`` set =
-               the full 5-step matching pipeline
+               the full 5-step matching pipeline (on the fleet: served
+               match requests, verified on the wavefront's band mode)
 index          index kind from the retrieval registry
                (``refnet|covertree|mv|linear|embedding|...``)
 execution      ``host`` (sequential frontier drive, classic counts),
@@ -168,9 +169,13 @@ class RetrievalConfig:
                 raise ValueError(
                     "fleet execution needs workers (a name tuple or count)")
             if self.lam is not None:
-                raise ValueError(
-                    "fleet execution serves window-level range queries; "
-                    "the matching pipeline (lam) runs host/batched")
+                from repro.kernels import registry as kernels
+                if not (kernels.has(dist.name)
+                        and kernels.get(dist.name).kind == "wavefront"):
+                    raise ValueError(
+                        "fleet matching (lam) verifies start pairs on the "
+                        "wavefront kernel's band mode; distance "
+                        f"{dist.name!r} has no wavefront kernel")
             if self.index != "refnet":
                 raise ValueError(
                     "fleet execution shards per-worker reference nets; "

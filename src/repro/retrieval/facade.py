@@ -25,7 +25,11 @@ the config:
   (``fleet_mode="rounds"``, one packed fused-ε dispatch per merged round),
   the legacy one-shot stacked device query via ``fleet_mode="oneshot"`` or
   ``.via("fleet-oneshot")``.  Hits are global window ids and
-  :meth:`Retriever.elastic` exposes resize / dead-worker controls.
+  :meth:`Retriever.elastic` exposes resize / dead-worker controls.  With
+  ``lam`` set the fleet indexes the sequences' windows and serves type I
+  match requests (``serve()``); ``query(Q).range(eps)`` runs the same
+  served rounds in process and returns
+  :class:`~repro.core.matching.MatchPair` lists.
 
 Every call returns a uniform :class:`ResultSet`: hits plus the
 ``{query, build}`` exact-evaluation buckets and dispatch counts of the
@@ -317,8 +321,14 @@ class _FleetEngine:
     """execution='fleet': the PR-3 elastic sharded serving layer."""
 
     def __init__(self, cfg: RetrievalConfig, data):
+        from repro.core.matching import MatchScope
         from repro.launch.elastic import ElasticIndex
         self.cfg = cfg
+        #: lam set: the sequences and their windows, which the fleet indexes
+        self.scope = None
+        if cfg.lam is not None:
+            self.scope = MatchScope(data, cfg.lam, cfg.lambda0)
+            data = self.scope.windows
         self.fleet = ElasticIndex(
             cfg.dist, data, list(cfg.workers), eps_prime=cfg.eps_prime,
             tight_bounds=cfg.tight_bounds, backend=cfg.effective_backend,
@@ -370,8 +380,9 @@ class Retriever:
         """Build the configured stack over ``data``.
 
         ``data`` is a sequence list for the matching pipeline (``lam``
-        set), a ``(N, l[, d])`` window array for window-level retrieval,
-        or ``(N, d)`` pooled vectors for ``index='embedding'``.
+        set, on the fleet too), a ``(N, l[, d])`` window array for
+        window-level retrieval, or ``(N, d)`` pooled vectors for
+        ``index='embedding'``.
         """
         if not isinstance(config, RetrievalConfig):
             raise TypeError(
@@ -408,7 +419,9 @@ class Retriever:
         over this retriever's fleet (PR 9): asynchronous requests join the
         shared frontier cadence mid-flight, one packed dispatch per merged
         round, zero-downtime snapshot-swap ``resize()``.  Configured by the
-        ``serve_*`` config fields; fleet-only."""
+        ``serve_*`` config fields; fleet-only.  With ``lam`` set it serves
+        subsequence-match requests: a query sequence in, its sorted
+        ``MatchPair`` list out."""
         if self._mode != "fleet":
             raise ValueError(
                 "serve() requires execution='fleet' "
@@ -418,7 +431,7 @@ class Retriever:
         return ServeEngine(self._engine.fleet, ServeConfig(
             eps=eps, max_inflight=cfg.serve_max_inflight,
             admission=cfg.serve_admission,
-            snapshot_dir=cfg.serve_snapshot_dir))
+            snapshot_dir=cfg.serve_snapshot_dir), scope=self._engine.scope)
 
     # -- introspection -------------------------------------------------------
 
@@ -509,12 +522,26 @@ class Retriever:
                                             plan._lb)
             if execution == "batched":
                 rounds = self._engine.rounds - r0
+        elif self._engine.scope is not None:
+            per_q = self._serve_matches(plan, eps)
         else:
             per_q = self._engine.range_many(plan._queries, eps, execution,
                                             extra_dead=plan._dead,
                                             lb=plan._lb)
         hits = per_q if plan._is_batch else per_q[0]
         return self._finish(hits, before, rounds=rounds)
+
+    def _serve_matches(self, plan: QueryPlan, eps: float) -> list:
+        """Fleet matching: the queries through the serve engine's rounds,
+        in this thread, until every one is answered."""
+        if plan._execution is not None or plan._dead or plan._lb is not None:
+            raise ValueError("fleet match requests run the served path; "
+                             "via(), dead() and lb() do not apply")
+        engine = self.serve(eps)
+        reqs = [engine.submit(Q, now=0.0) for Q in plan._queries]
+        while not all(r.done for r in reqs):
+            engine.tick(now=0.0)
+        return [r.hits for r in reqs]
 
     def _auto_eps_max(self, Q, execution, lb=None) -> Optional[float]:
         """Double eps from the index scale until the filter fires."""

@@ -21,6 +21,25 @@ asynchronous traffic.  Requests submitted at any time land in a
    requests, shards, and length buckets — retiring finished requests'
    rows immediately.
 
+Subsequence matching (type I, paper §7): an engine opened with a
+:class:`~repro.core.matching.MatchScope` serves match requests, a whole
+query sequence in and every similar (database subsequence, query
+subsequence) pair out.  Such a request goes through three stages:
+
+* **admission** runs Algorithm 3 for every query segment (step 3) on
+  every shard: one range plan per shard with one row per segment
+  (``range_query_rows_plan``; a window request's plan has one row), the
+  segments' ragged rows in its ``ShardPlans``;
+* the shared rounds run those plans like any other (step 4);
+* **verify**: once its plans are done, its (segment, window) hits give
+  the §7 candidate envelope, grouped by start pair (``match.candidates``);
+  each start pair is one row of the tick's one band-mode wavefront
+  dispatch (``match.verify``, ``dispatch.packed_band``), in the tick its
+  plans finished; **collect** keeps each candidate end cell within ε, and
+  the request finishes with those pairs, sorted.
+
+Window requests take the path above unchanged.
+
 Admission policy: ``"tick"`` (default) merges newcomers straight into
 the next shared round — strictly fewest dispatches; ``"greedy"`` gives
 newcomers one dedicated round first when older requests are already
@@ -58,6 +77,8 @@ import numpy as np
 
 from repro import spans
 from repro.core.batch_engine import FleetBatchEngine, ShardPlans
+from repro.core.matching import MatchScope, StartPairs
+from repro.kernels import dispatch
 from repro.serve.queue import Request, RequestQueue
 from repro.serve.snapshot import FleetSnapshotManager
 
@@ -84,6 +105,27 @@ class ServeConfig:
                 f"got {self.admission!r}")
 
 
+#: ``engine_stats()`` counters of the match path's verify stage, and of
+#: the largest rounds (``largest_filter_rows``: the merged round;
+#: ``largest_verify_rows``: the verify dispatch).  ``candidates``:
+#: candidate pairs, ``verify_rows``: their start pairs, ``candidates_ns``:
+#: host time spent building them; ``verify_cells``: the verify rows' real
+#: ``len_x * len_y`` summed, ``verify_elems`` their ``len_x + len_y``,
+#: ``verify_band_cells`` the end cells they emit.  (The merged rounds'
+#: rows are ``filter_rows``/``filter_cells``/``filter_elems``.)
+COUNTERS = ("match_requests", "segment_plans", "segment_hits",
+            "candidates", "verify_rows", "verify_cells", "verify_elems",
+            "verify_band_cells", "match_pairs", "candidates_ns",
+            "largest_filter_rows", "largest_verify_rows")
+
+
+@dataclasses.dataclass
+class _Verify:
+    """A match request whose plans are done, and its start pairs."""
+    req: Request
+    pairs: StartPairs
+
+
 def _snapshot_builds(fleet) -> int:
     """Plan snapshots built by the fleet's shard nets, summed."""
     return sum(s.net.snapshot_builds for s in fleet.shards.values()
@@ -94,9 +136,13 @@ class ServeEngine:
     """Continuous-batching front end over an ElasticIndex fleet."""
 
     def __init__(self, fleet, config: Optional[ServeConfig] = None, *,
-                 clock=time.monotonic):
+                 clock=time.monotonic, scope: Optional[MatchScope] = None):
         self.fleet = fleet
         self.config = config or ServeConfig()
+        #: the sequences and windows behind the fleet (match requests)
+        self.scope = scope
+        self.counts = dict.fromkeys(COUNTERS, 0)
+        self._verifying: List[_Verify] = []
         self.clock = clock
         self.queue = RequestQueue()
         evaluate, fused = fleet._round_evaluator()
@@ -104,8 +150,10 @@ class ServeEngine:
         # name + kernel policy only (shape-generic), so it keeps serving
         # across fleet swaps
         self._engine = FleetBatchEngine(evaluate, fused=fused)
-        #: bid -> (request, per-group gids captured at admit time)
-        self._inflight: Dict[int, Tuple[Request, List[np.ndarray]]] = {}
+        #: bid -> (request, per-group gids captured at admit time, and a
+        #: match request's segment starts and lengths)
+        self._inflight: Dict[int, Tuple[Request, List[np.ndarray],
+                                        Optional[tuple]]] = {}
         self.completed: List[Request] = []
         self.swaps = 0
         self._pending_swap = None
@@ -118,12 +166,19 @@ class ServeEngine:
 
     def submit(self, query: np.ndarray, eps: Optional[float] = None, *,
                tag: Optional[object] = None,
-               now: Optional[float] = None) -> Request:
+               now: Optional[float] = None,
+               match: Optional[bool] = None) -> Request:
         """Enqueue a range query; returns its handle (``req.result()``
-        blocks until served when the engine runs on a thread)."""
+        blocks until served when the engine runs on a thread).  ``match``
+        (default: whether the engine has a match scope) makes it a
+        subsequence-match request, ``query`` a whole query sequence."""
+        match = self.scope is not None if match is None else bool(match)
+        if match and self.scope is None:
+            raise ValueError("match requests need an engine opened with a "
+                             "MatchScope (a retriever built with lam)")
         return self.queue.submit(
             query, self.config.eps if eps is None else eps, tag=tag,
-            now=self.clock() if now is None else now)
+            now=self.clock() if now is None else now, match=match)
 
     # -- admission + rounds -------------------------------------------------
 
@@ -158,7 +213,14 @@ class ServeEngine:
             fleet = self.fleet
             builds = _snapshot_builds(fleet)
             q = np.asarray(req.query)
-            qpad, q_lens = q[None], np.asarray([len(q)], np.int64)
+            segs = None
+            if req.match:
+                with spans.span(spans.MATCH_SEGMENTS, rid=req.rid) as ms:
+                    qpad, q_lens, starts = self.scope.segments(q)
+                    segs = (starts, q_lens)
+                    ms.set_metadata(segments=len(q_lens))
+            else:
+                qpad, q_lens = q[None], np.asarray([len(q)], np.int64)
             hook = self._lb_hook(fleet)
             groups: List[ShardPlans] = []
             gids: List[np.ndarray] = []
@@ -166,14 +228,20 @@ class ServeEngine:
                 s = fleet.shards.get(w)
                 if s is None:
                     continue
+                # one plan per shard over the request's query rows: the
+                # query, or a match request's segments
                 groups.append(ShardPlans(
                     shard=si, data=s.net.data,
-                    plans=[s.net.range_query_plan(req.eps)],
+                    plans=[s.net.range_query_rows_plan(req.eps,
+                                                       len(q_lens))],
                     queries=qpad, q_lens=q_lens, lb=hook))
                 gids.append(s.gids)
+            if req.match:
+                self.counts["match_requests"] += 1
+                self.counts["segment_plans"] += len(q_lens) * len(groups)
             req.t_admit = now
             bid = self._engine.admit(groups, req.eps)
-            self._inflight[bid] = (req, gids)
+            self._inflight[bid] = (req, gids, segs)
             # plan snapshots this admission built (0 once every shard's
             # is cached)
             sp.set_metadata(snapshot_builds=_snapshot_builds(fleet) - builds)
@@ -182,17 +250,92 @@ class ServeEngine:
             return None
         return bid
 
-    def _finalize(self, bid: int, now: float) -> Request:
-        req, gids = self._inflight.pop(bid)
+    def _finalize(self, bid: int, now: float) -> Optional[Request]:
+        """A batch whose plans are done: a window request finishes, a
+        match request goes on to verify (None, unless it has no
+        candidate)."""
+        req, gids, segs = self._inflight.pop(bid)
         with spans.span(spans.SERVE_FINALIZE, rid=req.rid) as sp:
             per_group = self._engine.results(bid)
-            hits = set()
-            for g, res in zip(gids, per_group):
-                hits.update(int(g[x]) for x in res[0])
-            sp.set_metadata(hits=len(hits))
-            req.finish(sorted(hits), now)
+            if segs is not None:
+                pairs = self._candidates(req, gids, segs, per_group)
+                sp.set_metadata(hits=len(pairs))
+                if len(pairs):
+                    self._verifying.append(_Verify(req, pairs))
+                    return None
+                hits = []
+            else:
+                hits = set()
+                for g, res in zip(gids, per_group):
+                    hits.update(int(g[x]) for x in res[0][0])
+                hits = sorted(hits)
+                sp.set_metadata(hits=len(hits))
+            req.finish(hits, now)
         self.completed.append(req)
         return req
+
+    def _candidates(self, req: Request, gids, segs, per_group
+                    ) -> StartPairs:
+        """Steps 4 -> 5: the request's (segment, window) hits as global
+        windows, and their candidate envelope by start pair."""
+        with spans.span(spans.MATCH_CANDIDATES, rid=req.rid) as sp:
+            t0 = time.perf_counter_ns()
+            plan, win = [], []
+            for g, res in zip(gids, per_group):
+                for i, local in enumerate(res[0]):   # one list per segment
+                    if local:
+                        plan.append(np.full(len(local), i, np.int64))
+                        win.append(g[np.asarray(local, np.int64)])
+            plan = np.concatenate(plan) if plan else np.zeros(0, np.int64)
+            win = np.concatenate(win) if win else np.zeros(0, np.int64)
+            starts, lens = segs
+            pairs = self.scope.candidates(len(req.query), starts[plan],
+                                          lens[plan], win)
+            n_cand = int(pairs.mask.sum())
+            sp.set_metadata(hits=len(win), candidates=n_cand,
+                            rows=len(pairs))
+            c = self.counts
+            c["segment_hits"] += len(win)
+            c["candidates"] += n_cand
+            c["candidates_ns"] += time.perf_counter_ns() - t0
+        return pairs
+
+    def _verify_round(self, now: float) -> List[Request]:
+        """The tick's one verify dispatch over every request in verify:
+        one band-mode row per start pair, then each request's pairs."""
+        jobs, self._verifying = self._verifying, []
+        scope, fleet, c = self.scope, self.fleet, self.counts
+        with spans.span(spans.MATCH_VERIFY, requests=len(jobs)) as sp:
+            rows = [scope.verify_rows(j.req.query, j.pairs) for j in jobs]
+            lx = np.concatenate([j.pairs.lx for j in jobs])
+            ly = np.concatenate([j.pairs.ly for j in jobs])
+            eps = np.concatenate([np.full(len(j.pairs), j.req.eps,
+                                          np.float32) for j in jobs])
+            band = dispatch.packed_band(
+                fleet.dist.name, np.concatenate([r[0] for r in rows]),
+                np.concatenate([r[1] for r in rows]), lx, ly,
+                lam=scope.lam, lambda0=scope.lambda0, eps=eps,
+                interpret=fleet.interpret, exec=fleet.kernel_exec,
+                tile=fleet.kernel_tile)
+            sp.set_metadata(rows=len(lx))
+        c["verify_rows"] += len(lx)
+        c["verify_cells"] += int(np.dot(lx, ly))
+        c["verify_elems"] += int(lx.sum() + ly.sum())
+        c["verify_band_cells"] += scope.band_cells(lx, ly)
+        c["largest_verify_rows"] = max(c["largest_verify_rows"], len(lx))
+        done, off = [], 0
+        for j in jobs:
+            n, req = len(j.pairs), j.req
+            req.rounds += 1
+            with spans.span(spans.MATCH_COLLECT, rid=req.rid) as cs:
+                pairs = scope.collect(j.pairs, band[off:off + n], req.eps)
+                cs.set_metadata(pairs=len(pairs))
+            c["match_pairs"] += len(pairs)
+            req.finish(pairs, now)
+            self.completed.append(req)
+            done.append(req)
+            off += n
+        return done
 
     def _round(self, now: float,
                only: Optional[Set[int]] = None) -> List[Request]:
@@ -206,8 +349,12 @@ class ServeEngine:
             req.rounds += 1
             if math.isnan(req.t_first_dispatch):
                 req.t_first_dispatch = now
-        return [self._finalize(bid, now)
-                for bid in self._engine.step(only=only)]
+        finished = self._engine.step(only=only)
+        c = self.counts
+        c["largest_filter_rows"] = max(c["largest_filter_rows"],
+                                       self._engine.last_rows)
+        done = (self._finalize(bid, now) for bid in finished)
+        return [r for r in done if r is not None]
 
     def tick(self, now: Optional[float] = None) -> List[Request]:
         """One scheduler beat: swap -> admit -> (greedy round) -> shared
@@ -235,6 +382,8 @@ class ServeEngine:
                 done.extend(self._round(now, only=newly))
             if self._engine.active:
                 done.extend(self._round(now))
+            if self._verifying:
+                done.extend(self._verify_round(now))
             return done
 
     # -- zero-downtime resize ----------------------------------------------
@@ -356,8 +505,10 @@ class ServeEngine:
     # -- accounting ---------------------------------------------------------
 
     def engine_stats(self) -> Dict[str, int]:
-        """Shared-cadence totals (merged rounds, eval split, swaps) and the
-        plan snapshots the fleet's shard nets have built."""
+        """Shared-cadence totals (merged rounds, eval split, swaps), the
+        plan snapshots the fleet's shard nets have built, the merged
+        rounds' rows (``filter_rows``) with their real cells and elements,
+        and the match path's :data:`COUNTERS`."""
         e = self._engine
         return {"rounds": e.rounds, "exact_evals": e.exact_evals,
                 "verdict_evals": e.verdict_evals,
@@ -365,7 +516,10 @@ class ServeEngine:
                 "lb_rows": e.lb_rows, "lb_pruned": e.lb_pruned,
                 "submitted": self.queue.submitted,
                 "completed": len(self.completed), "swaps": self.swaps,
-                "plan_snapshot_builds": _snapshot_builds(self.fleet)}
+                "plan_snapshot_builds": _snapshot_builds(self.fleet),
+                "filter_rows": e.exact_evals + e.verdict_evals,
+                "filter_cells": e.cells, "filter_elems": e.elems,
+                **self.counts}
 
     def latency_stats(self) -> Dict[str, float]:
         """Per-request latency percentiles over the completed set (clock

@@ -41,8 +41,11 @@ class Request:
     t_admit: float = math.nan
     t_first_dispatch: float = math.nan
     t_complete: float = math.nan
-    rounds: int = 0                       # merged rounds this request rode in
-    hits: Optional[List[int]] = None      # sorted global window ids
+    rounds: int = 0     # merged rounds (and a match's verify tick) it rode in
+    #: a subsequence-match request (type I over a sequence database): its
+    #: ``hits`` are then the sorted ``MatchPair``s, not window ids
+    match: bool = False
+    hits: Optional[list] = None           # sorted global window ids
     _done: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False, compare=False)
 
@@ -83,9 +86,10 @@ class RequestQueue:
         self.submitted = 0
 
     def submit(self, query: np.ndarray, eps: float, *,
-               tag: Optional[object] = None, now: float = 0.0) -> Request:
+               tag: Optional[object] = None, now: float = 0.0,
+               match: bool = False) -> Request:
         req = Request(rid=-1, query=np.asarray(query), eps=float(eps),
-                      tag=tag, t_submit=float(now))
+                      tag=tag, t_submit=float(now), match=match)
         with self._lock:
             req.rid = self._next_rid
             self._next_rid += 1

@@ -14,6 +14,14 @@ span would time tracing, not the call).  Nesting, outermost first::
     fleet.round > fleet.lb | fleet.gather | fleet.evaluate | fleet.resume
     fleet.evaluate > dispatch.pack | dispatch.pad | dispatch.launch
                      | dispatch.fetch | dispatch.unpack
+
+A subsequence-match request adds its own stages (:data:`MATCH_NAMES`)::
+
+    serve.admit > match.segments
+    serve.finalize > match.candidates
+    serve.tick > match.verify > dispatch.pack | dispatch.pad
+                              | dispatch.launch | dispatch.fetch
+               | match.collect
 """
 
 from __future__ import annotations
@@ -52,6 +60,27 @@ DISPATCH_LAUNCH = "dispatch.launch"
 DISPATCH_FETCH = "dispatch.fetch"
 #: ``packed_batch``'s inverse permutation back to the caller's order
 DISPATCH_UNPACK = "dispatch.unpack"
+
+#: a match request's query segments and their plans on every shard
+#: (``rid``, ``segments``)
+MATCH_SEGMENTS = "match.segments"
+#: its (segment, window) hits to the §7 candidate envelope, grouped by
+#: start pair (``rid``, ``hits``, ``candidates``, ``rows``)
+MATCH_CANDIDATES = "match.candidates"
+#: the tick's one verify dispatch: pack, band-mode wavefront, unpack
+#: (``requests``, ``rows``)
+MATCH_VERIFY = "match.verify"
+#: one request's verified end cells to its sorted pairs (``rid``,
+#: ``pairs``)
+MATCH_COLLECT = "match.collect"
+
+#: the match path's spans.  They sit apart from :data:`NAMES` because
+#: ``NAMES`` is the set the benchmark's span reader (``bench/progtrace.py``,
+#: ``PROGRAM``) splits a round by, and its tests hold the two equal; the
+#: match spans join ``NAMES`` in the same change that adds them to
+#: ``PROGRAM``.  Until then ``bench/split.py`` does not split them out.
+MATCH_NAMES = (MATCH_SEGMENTS, MATCH_CANDIDATES, MATCH_VERIFY,
+               MATCH_COLLECT)
 
 NAMES = (SERVE_TICK, SERVE_ADMIT, SERVE_FINALIZE, FLEET_ROUND, FLEET_LB,
          FLEET_GATHER, FLEET_EVALUATE, FLEET_RESUME, DISPATCH_PACK,

@@ -226,6 +226,55 @@ def test_array_plan_equals_the_node_walk(dist_name, tight, kind, change,
     assert net.snapshot_builds == 1
 
 
+def record_rows(plan, counter: CountedDistance, queries):
+    """Drive a plan over several query rows; returns, per row, the
+    frontiers it asked for that row (ids, kind), its hits and the exact
+    evaluations spent."""
+    frontiers = [[] for _ in queries]
+    evals = [0] * len(queries)
+    try:
+        fr = next(plan)
+        while True:
+            ds = np.empty(len(fr.idxs), np.float32)
+            for r in np.unique(fr.rows):
+                at = fr.rows == r
+                frontiers[r].append((fr.idxs[at].tolist(), fr.kind))
+                c0 = counter.count
+                ds[at] = counter.eval(queries[r], fr.idxs[at],
+                                      len(queries[r]))
+                evals[r] += counter.count - c0
+            fr = plan.send(ds)
+    except StopIteration as stop:
+        hits = stop.value
+    return list(zip(frontiers, hits, evals))
+
+
+@pytest.mark.parametrize("change", ["built", "delete"])
+@pytest.mark.parametrize("kind", sorted(INDEXES))
+@pytest.mark.parametrize("dist_name", sorted(DISTANCES))
+@settings(max_examples=2, deadline=None)
+@given(seed=st.integers(0, 2 ** 32 - 1))
+def test_plan_over_many_rows_equals_one_plan_per_row(dist_name, kind,
+                                                    change, seed):
+    """One plan over several query rows (a match request's segments on a
+    shard) asks each row for the frontiers, and gives it the hits and the
+    exact evaluations, of that row's own plan."""
+    rng = np.random.default_rng(seed)
+    net, data = _changed_net(change, kind, dist_name, True, rng)
+    counter = net.counter
+    queries = [np.asarray(data[int(i)], data.dtype)
+               for i in rng.choice(len(data), 5)]
+    queries[1] = queries[0]     # a repeated row is still a row of its own
+    d = counter.eval(queries[2], np.asarray(sorted(net.nodes)),
+                     len(queries[2]))
+    for eps in (-1.0, float(np.quantile(d, 0.05)), float(np.median(d))):
+        want = [record(net.range_query_plan(eps), counter, q)
+                for q in queries]
+        got = record_rows(net.range_query_rows_plan(eps, len(queries)),
+                          counter, queries)
+        assert got == [tuple(w) for w in want]
+
+
 def test_snapshot_is_cached_until_the_net_changes():
     rng = np.random.default_rng(5)
     net, data = _changed_net("built", "net", "levenshtein", True, rng)

@@ -83,7 +83,8 @@ def test_config_rejects_unknown_names():
     (dict(lam=8, lambda0=4), "lambda0"),
     (dict(lam=8, index="embedding"), "embedding"),
     (dict(execution="fleet"), "workers"),
-    (dict(execution="fleet", workers=2, lam=8), "matching pipeline"),
+    (dict(execution="fleet", workers=2, lam=8, index="embedding"),
+     "matching pipeline"),
     (dict(execution="fleet", workers=2, index="mv"), "refnet"),
     (dict(execution="fleet", workers=2, lb_cascade=True), "lb_cascade"),
     (dict(workers=("a",)), "fleet"),

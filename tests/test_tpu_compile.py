@@ -100,3 +100,25 @@ def test_envelope_specs_compile(one_chip, name):
     # pure elementwise jnp: XLA fuses it, no Pallas kernel expected
     hlo = _compile(one_chip, name, 256, 20, 20, 2)
     assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("exec_mode", ["pallas", "scan"])
+def test_band_mode_compiles_at_the_match_cell(one_chip, exec_mode):
+    # protein_match: lam 40, l 20, lambda0 2; a start-pair row is
+    # lam + l = 60 database residues against 62 query residues, and the
+    # cell's largest verify class is its traffic file's verify_warm_rows
+    B = 65536
+    hlo = _compile(one_chip, "levenshtein", B, 60, 62, 1, exec=exec_mode,
+                   band=(40, 2))
+    assert ("wavefront_lev_band" in hlo) == (exec_mode == "pallas")
+
+
+def test_wide_block_compiles_at_the_filter_class(one_chip):
+    # a merged round of protein_match segment rows (22 residues against
+    # windows of 20) at registry.MAX_BATCH rows takes the wide block
+    B = registry.MAX_BATCH
+    block = registry.default_block(B)
+    assert block == registry.WIDE_BLOCK
+    hlo = _compile(one_chip, "levenshtein", B, 22, 20, 1, exec="pallas",
+                   block_b=block)
+    assert "wavefront_lev" in hlo

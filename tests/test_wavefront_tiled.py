@@ -320,3 +320,160 @@ def test_fleet_mode_scan_matches_host():
     want = r.batch(queries).via("host").range(2.0)
     got = r.batch(queries).range(2.0)
     assert got.hits == want.hits, "fleet scan-backend hit drift"
+
+
+# -- band mode (start-pair rows of subsequence matching) ----------------------
+
+
+def _full_table(name, x, y):
+    """The whole DP table ``D[0..len(x), 0..len(y)]``, cell by cell."""
+    if name == "levenshtein":
+        cost = lambda a, b: float(a != b)  # noqa: E731
+        gx = gy = lambda a: 1.0  # noqa: E731
+    else:  # erp, gap element 0
+        norm = lambda a: float(np.sqrt(np.sum(np.square(  # noqa: E731
+            np.asarray(a, np.float64)))))
+        cost = lambda a, b: norm(np.asarray(a, np.float64) - b)  # noqa
+        gx = gy = norm
+    D = np.zeros((len(x) + 1, len(y) + 1))
+    for i in range(1, len(x) + 1):
+        D[i, 0] = D[i - 1, 0] + gx(x[i - 1])
+    for j in range(1, len(y) + 1):
+        D[0, j] = D[0, j - 1] + gy(y[j - 1])
+    for i in range(1, len(x) + 1):
+        for j in range(1, len(y) + 1):
+            D[i, j] = min(D[i - 1, j - 1] + cost(x[i - 1], y[j - 1]),
+                          D[i - 1, j] + gx(x[i - 1]),
+                          D[i, j - 1] + gy(y[j - 1]))
+    return D
+
+
+def _band_want(name, xs, ys, lx, ly, lam, l0, Lx, eps=np.inf):
+    want = np.full((len(xs), Lx - lam + 1, 2 * l0 + 1), np.inf)
+    for r in range(len(xs)):
+        D = _full_table(name, xs[r, :lx[r]], ys[r, :ly[r]])
+        for i in range(lam, lx[r] + 1):
+            for q in range(2 * l0 + 1):
+                j = i + q - l0
+                if lam <= j <= ly[r] and D[i, j] <= eps:
+                    want[r, i - lam, q] = D[i, j]
+    return want
+
+
+@pytest.mark.parametrize("name", ["levenshtein", "erp"])
+def test_band_mode_equals_full_table_across_tiles(name):
+    """Every end cell ``(i, j)``, ``i, j >= lam``, ``|i - j| <= lambda0``,
+    of ragged start-pair rows: the scan twin against the full table, and
+    the Pallas kernel (interpret) bit-identical to it at every band
+    split, the single band included."""
+    rng = np.random.default_rng(29)
+    B, Lx, Ly, lam, l0 = 13, 14, 16, 6, 2
+    xs, ys, _, _ = _ragged(name, B, Lx, Ly, rng, d=2)
+    lx = rng.integers(lam - 1, Lx + 1, B)   # one row may emit nothing
+    ly = rng.integers(lam, Ly + 1, B)
+    band = dict(lam=lam, lambda0=l0, eps=np.inf)
+    base = dispatch.packed_band(name, xs, ys, lx, ly, exec="scan", **band)
+    assert base.shape == (B, Lx - lam + 1, 2 * l0 + 1)
+    want = _band_want(name, xs, ys, lx, ly, lam, l0, Lx)
+    if name == "levenshtein":
+        np.testing.assert_array_equal(base, want)
+    else:
+        np.testing.assert_allclose(base, want, rtol=1e-5)
+    for tile in (3, 7, None):
+        got = dispatch.packed_band(name, xs, ys, lx, ly, exec="pallas",
+                                   tile=tile, **band)
+        np.testing.assert_array_equal(got, base, err_msg=f"tile {tile}")
+
+
+def test_band_mode_fused_eps_loses_no_cell():
+    """Per-row eps: every cell <= its row's eps comes back exact, every
+    other cell +inf, in both execution modes."""
+    rng = np.random.default_rng(31)
+    B, Lx, Ly, lam, l0 = 11, 12, 14, 5, 1
+    xs = rng.integers(0, 3, size=(B, Lx))
+    ys = xs[:, :Ly].copy() if Ly <= Lx else np.concatenate(
+        [xs, rng.integers(0, 3, size=(B, Ly - Lx))], axis=1)
+    ys[rng.random(ys.shape) < 0.3] = 2
+    lx = rng.integers(lam, Lx + 1, B)
+    ly = rng.integers(lam, Ly + 1, B)
+    eps = rng.integers(0, 5, B).astype(np.float32)
+    out = {ex: dispatch.packed_band("levenshtein", xs, ys, lx, ly, lam=lam,
+                                    lambda0=l0, eps=eps, exec=ex, tile=4)
+           for ex in ("scan", "pallas")}
+    want = np.stack([_band_want("levenshtein", xs[r:r + 1], ys[r:r + 1],
+                                lx[r:r + 1], ly[r:r + 1], lam, l0, Lx,
+                                eps[r])[0] for r in range(B)])
+    assert np.isfinite(want).sum() > 0 and np.isinf(want).sum() > 0
+    np.testing.assert_array_equal(out["scan"], want)
+    np.testing.assert_array_equal(out["pallas"], out["scan"])
+
+
+def test_band_mode_compiles_once_per_batch_class():
+    """Fixed padded widths: one jit entry per power-of-two batch."""
+    rng = np.random.default_rng(37)
+    registry.clear_cache()
+    for B in (3, 5, 8, 9):
+        xs = rng.integers(0, 4, size=(B, 12))
+        ys = rng.integers(0, 4, size=(B, 13))
+        dispatch.packed_band("levenshtein", xs, ys, rng.integers(8, 13, B),
+                             rng.integers(8, 14, B), lam=8, lambda0=1,
+                             eps=2.0, exec="scan")
+    keys = registry.cache_keys()
+    assert registry.STATS["traces"] == 2 == len(keys)
+    assert {k.batch for k in keys} == {8, 16}
+    assert {k.band for k in keys} == {(8, 1)}
+
+
+@pytest.mark.parametrize("band", [False, True])
+def test_burst_past_the_largest_class_compiles_nothing_new(monkeypatch,
+                                                           band):
+    """A batch larger than ``registry.MAX_BATCH`` goes as calls of the
+    warmed classes: no new compile, and the answers of one whole call."""
+    rng = np.random.default_rng(41)
+    B, lam, l0 = 70, 8, 1
+    xs = rng.integers(0, 4, size=(B, 12))
+    ys = rng.integers(0, 4, size=(B, 13))
+    lx, ly = rng.integers(8, 13, B), rng.integers(8, 14, B)
+    eps = rng.integers(0, 6, B).astype(np.float32)
+
+    def call(n):
+        if band:
+            return dispatch.packed_band(
+                "levenshtein", xs[:n], ys[:n], lx[:n], ly[:n], lam=lam,
+                lambda0=l0, eps=eps[:n], exec="scan")
+        return dispatch.packed_batch("levenshtein", xs[:n], ys[:n],
+                                     lx[:n], ly[:n], eps=eps[:n],
+                                     exec="scan", trim=False)
+
+    registry.clear_cache()
+    want = call(B)                      # one call of the 128-row class
+    monkeypatch.setattr(registry, "MAX_BATCH", 16)
+    for n in (8, 16):                   # the warm-up: every class <= 16
+        call(n)
+    traces = registry.STATS["traces"]
+    got = call(B)
+    assert registry.STATS["traces"] == traces
+    assert len(got) == len(want)    # band rows, or the three fields
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_wide_batches_take_the_wide_block(monkeypatch):
+    """At ``WIDE_BATCH`` padded rows or more a grid step takes
+    ``WIDE_BLOCK`` rows (band mode keeps 8), and the answers are the
+    scan twin's, bit for bit."""
+    assert registry.default_block(registry.WIDE_BATCH) == registry.WIDE_BLOCK
+    assert registry.default_block(registry.WIDE_BATCH // 2) == 8
+    assert registry.default_block(registry.WIDE_BATCH, band=(40, 2)) == 8
+    monkeypatch.setattr(registry, "WIDE_BATCH", 64)
+    rng = np.random.default_rng(43)
+    B = 40                              # padded to 64: the wide block
+    xs = rng.integers(0, 4, size=(B, 12))
+    ys = rng.integers(0, 4, size=(B, 11))
+    lx, ly = rng.integers(6, 13, B), rng.integers(6, 12, B)
+    out = {ex: dispatch.packed_batch("levenshtein", xs, ys, lx, ly, eps=3.0,
+                                     exec=ex) for ex in ("pallas", "scan")}
+    assert {k.block_b for k in registry.cache_keys()
+            if k.batch == 64} == {registry.WIDE_BLOCK}
+    for a, b in zip(out["pallas"], out["scan"]):
+        np.testing.assert_array_equal(a, b)
